@@ -4,8 +4,11 @@ from __future__ import annotations
 
 import time
 
+from repro.utils import init_compile_cache
+
 
 def main() -> None:
+    init_compile_cache()
     from benchmarks import (fig1_rates, fig2_throughput, kernels_micro,
                             kvsharer_bench, roofline, serving_continuous,
                             table1_selective, table2_quant,

@@ -4,6 +4,7 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:
@@ -13,15 +14,28 @@ def resolve_interpret(interpret: Optional[bool]) -> bool:
     return jax.default_backend() != "tpu" if interpret is None else interpret
 
 
-def pick_block(S: int, unit: int, target: int) -> int:
-    """Largest multiple of `unit` that divides S and is <= target.
+def tile(S: int, step: int, target: int) -> tuple[int, int]:
+    """(block, padded length) for a grid axis the TPU compiler will tile.
 
-    Kernels snap their requested block size down with this so any
-    sequence length that tiles in `unit` steps (1 for dense stores, the
-    quantization group for packed ones) gets a legal grid."""
-    assert S % unit == 0, (S, unit)
-    best = unit
-    for bs in range(unit, min(target, S) + 1, unit):
-        if S % bs == 0:
-            best = bs
-    return best
+    Mosaic takes a block whose trailing dims are multiples of the (8, 128)
+    tiling or span the whole array. When S fits in max(target, step), one
+    block spans it, so any S is legal. Otherwise S is covered by the fewest
+    `step`-aligned blocks no wider than the target, spread evenly so the
+    padding is under one `step` per block. The caller pads the axis to the
+    returned length and masks the pad."""
+    if S <= max(target, step):
+        return S, S
+    cap = max(step, target - target % step)
+    n = -(-S // cap)
+    bs = -(-S // n)
+    bs += -bs % step
+    return bs, n * bs
+
+
+def pad_rows(x, n: int, fill=0):
+    """x padded by n rows of `fill` along axis 1 (the sequence axis of
+    every [B, S, ...] cache and attention operand)."""
+    if not n:
+        return x
+    return jnp.pad(x, [(0, 0), (0, n)] + [(0, 0)] * (x.ndim - 2),
+                   constant_values=fill)

@@ -45,7 +45,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.blocking import pick_block  # noqa: F401  (re-export)
+from repro.kernels.blocking import pad_rows, tile
 
 Array = jax.Array
 NEG_INF = -1e30
@@ -61,19 +61,24 @@ def _unpack(p: Array, bits: int, D: int) -> Array:
     return codes.reshape(*p.shape[:-1], D)
 
 
-def _kernel(*refs, bits: int, D: int, group: int, block_s: int, n_main: int,
-            ring_w: int, return_mass: bool, compute_dtype):
+def _kernel(*refs, bits: int, D: int, group: int, n_main: int, ring_w: int,
+            return_mass: bool, compute_dtype):
     """One (batch, kv-head, cache-block) grid cell.
 
     Ref layout (inputs, then outputs, then scratch — pieces that are
-    statically absent simply aren't passed):
+    statically absent simply aren't passed). The TPU compiler takes a
+    block only if its last two dims are (8, 128)-aligned or span the
+    array's, so per-key rows ride on a unit axis and per-token V scales
+    are columns:
 
       q [1,1,Gq,D];
       k [1,1,BS,Dp] (+ k_scale/k_zero [1,1,BS//G,D], v_scale/v_zero
-      [1,1,BS] when bits<16); v [1,1,BS,Dp]; bias_main [1,BS];
-      ring: rk/rv [1,1,W,D] + bias_ring [1,W] when ring_w>0;
-      out o [1,1,Gq,D] (+ mass [1,1,S+W] when return_mass);
-      scratch m/l [Gq,1], acc [Gq,D] (+ p [Gq,S+W] when return_mass).
+      [1,1,BS,1] when bits<16); v [1,1,BS,Dp]; bias_main [1,1,1,BS];
+      ring: rk/rv [1,1,W,D] + bias_ring [1,1,W] when ring_w>0;
+      out o [1,1,Gq,D] (+ mass [1,1,n_main,BS], and ring mass [1,1,1,W]
+      when ring_w>0, when return_mass);
+      scratch m/l [Gq,1], acc [Gq,D] (+ p [n_main,Gq,BS], and ring p
+      [Gq,W] when ring_w>0, when return_mass).
     """
     it = iter(refs)
     q_ref = next(it)
@@ -87,9 +92,13 @@ def _kernel(*refs, bits: int, D: int, group: int, block_s: int, n_main: int,
     if ring_w:
         rk_ref, rv_ref, biasr_ref = next(it), next(it), next(it)
     o_ref = next(it)
-    mass_ref = next(it) if return_mass else None
+    if return_mass:
+        mass_ref = next(it)
+        rmass_ref = next(it) if ring_w else None
     m_scr, l_scr, acc_scr = next(it), next(it), next(it)
-    p_scr = next(it) if return_mass else None
+    if return_mass:
+        p_scr = next(it)
+        pr_scr = next(it) if ring_w else None
 
     s_idx = pl.program_id(2)
     total = pl.num_programs(2)
@@ -101,13 +110,16 @@ def _kernel(*refs, bits: int, D: int, group: int, block_s: int, n_main: int,
         acc_scr[...] = jnp.zeros_like(acc_scr)
         if return_mass:
             p_scr[...] = jnp.zeros_like(p_scr)
+            if ring_w:
+                pr_scr[...] = jnp.zeros_like(pr_scr)
 
     q = q_ref[0, 0].astype(jnp.float32)                      # [Gq, D]
     scale = 1.0 / math.sqrt(D)
 
-    def attend(k, v, bias_row, start, width):
-        """Online-softmax update for one key block [width, D]."""
-        s = (q @ k.T) * scale + bias_row[None, :]            # [Gq, width]
+    def attend(k, v, bias_row):
+        """Online-softmax update for one key block [width, D]; returns
+        the block's probabilities relative to the new running max."""
+        s = (q @ k.T) * scale + bias_row                     # [Gq, width]
         m_prev = m_scr[...]                                  # [Gq, 1]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -117,9 +129,11 @@ def _kernel(*refs, bits: int, D: int, group: int, block_s: int, n_main: int,
         m_scr[...] = m_new
         if return_mass:
             # stored probabilities stay relative to the *current* max:
-            # rescale history, then drop in the fresh block.
-            p_scr[...] = p_scr[...] * alpha
-            p_scr[:, pl.dslice(start, width)] = p
+            # rescale history; the caller drops in the fresh block
+            p_scr[...] = p_scr[...] * alpha[None]
+            if ring_w:
+                pr_scr[...] = pr_scr[...] * alpha
+        return p
 
     @pl.when(s_idx < n_main)
     def _main_block():
@@ -130,26 +144,123 @@ def _kernel(*refs, bits: int, D: int, group: int, block_s: int, n_main: int,
             k = ((kc * ks + kz).astype(compute_dtype)
                  .astype(jnp.float32))
             vc = _unpack(v_ref[0, 0], bits, D).astype(jnp.float32)
-            v = ((vc * vs_ref[0, 0][:, None] + vz_ref[0, 0][:, None])
+            v = ((vc * vs_ref[0, 0] + vz_ref[0, 0])
                  .astype(compute_dtype).astype(jnp.float32))
         else:
             k = k_ref[0, 0].astype(jnp.float32)
             v = v_ref[0, 0].astype(jnp.float32)
-        attend(k, v, biasm_ref[0], s_idx * block_s, block_s)
+        p = attend(k, v, biasm_ref[0, 0])
+        if return_mass:
+            p_scr[s_idx] = p
 
     if ring_w:
         @pl.when(s_idx == n_main)
         def _ring_block():
             k = rk_ref[0, 0].astype(jnp.float32)
             v = rv_ref[0, 0].astype(jnp.float32)
-            attend(k, v, biasr_ref[0], n_main * block_s, ring_w)
+            p = attend(k, v, biasr_ref[0])
+            if return_mass:
+                pr_scr[...] = p
 
     @pl.when(s_idx == total - 1)
     def _done():
         l = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
         if return_mass:
-            mass_ref[0, 0] = (p_scr[...] / l).sum(axis=0)
+            mass_ref[0, 0] = (p_scr[...] / l[None]).sum(axis=1)
+            if ring_w:
+                rmass_ref[0, 0] = (pr_scr[...] / l).sum(axis=0,
+                                                         keepdims=True)
+
+
+def _call(B, Hkv, Gq, D, n_main, bs, W, q_dtype, *, bits, group,
+          return_mass, compute_dtype, interpret, idx, prefetch=0):
+    """Shared pallas_call assembly of the dense and block-table grids.
+
+    `idx(kind)` returns the index map of a main-store operand of `kind`
+    ("kv" for 4-d K/V/K-scale blocks, "bias" for the [B, n, 1, BS] row);
+    everything else is indexed by (batch, kv-head) alone. Index maps take
+    `prefetch` trailing scalar-prefetch refs. Returns the configured
+    callable and the in-spec list the caller zips with its operands."""
+    def fixed(*tail):
+        def f(b, h, s, *_):
+            return (b, h) + tail
+        return f
+
+    in_specs = [pl.BlockSpec((1, 1, Gq, D), fixed(0, 0)),
+                pl.BlockSpec((1, 1, bs, D * bits // 8 if bits < 16 else D),
+                             idx("kv"))]
+    if bits < 16:
+        in_specs += [pl.BlockSpec((1, 1, bs // group, D), idx("kv"))] * 2
+    in_specs.append(in_specs[1])
+    if bits < 16:
+        in_specs += [pl.BlockSpec((1, 1, bs, 1), idx("kv"))] * 2
+    in_specs.append(pl.BlockSpec((1, 1, 1, bs), idx("bias")))
+    if W:
+        in_specs += [pl.BlockSpec((1, 1, W, D), fixed(0, 0))] * 2
+        in_specs.append(pl.BlockSpec((1, 1, W), lambda b, h, s, *_: (b, 0, 0)))
+
+    out_shape = [jax.ShapeDtypeStruct((B, Hkv, Gq, D), q_dtype)]
+    out_specs = [pl.BlockSpec((1, 1, Gq, D), fixed(0, 0))]
+    scratch = [pltpu.VMEM((Gq, 1), jnp.float32),
+               pltpu.VMEM((Gq, 1), jnp.float32),
+               pltpu.VMEM((Gq, D), jnp.float32)]
+    if return_mass:
+        out_shape.append(jax.ShapeDtypeStruct((B, Hkv, n_main, bs),
+                                              jnp.float32))
+        out_specs.append(pl.BlockSpec((1, 1, n_main, bs), fixed(0, 0)))
+        scratch.append(pltpu.VMEM((n_main, Gq, bs), jnp.float32))
+        if W:
+            out_shape.append(jax.ShapeDtypeStruct((B, Hkv, 1, W),
+                                                  jnp.float32))
+            out_specs.append(pl.BlockSpec((1, 1, 1, W), fixed(0, 0)))
+            scratch.append(pltpu.VMEM((Gq, W), jnp.float32))
+
+    body = functools.partial(_kernel, bits=bits, D=D, group=group,
+                             n_main=n_main, ring_w=W,
+                             return_mass=return_mass,
+                             compute_dtype=compute_dtype)
+
+    def kernel(*refs):
+        # scalar-prefetch refs are only consumed by the index maps
+        body(*refs[prefetch:])
+
+    grid = (B, Hkv, n_main + (1 if W else 0))
+    if prefetch:
+        call = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=prefetch, grid=grid, in_specs=in_specs,
+                out_specs=out_specs, scratch_shapes=scratch),
+            out_shape=out_shape, interpret=interpret)
+    else:
+        call = pl.pallas_call(
+            kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+            out_shape=out_shape, scratch_shapes=scratch,
+            interpret=interpret)
+    return call
+
+
+def _side_operands(q, rk, rv, bias_ring, Hkv):
+    """q as [B, Hkv, Gq, D] and the ring pieces in kernel layout."""
+    B, Hq, D = q.shape
+    ops = [q.reshape(B, Hkv, Hq // Hkv, D)]
+    ring = []
+    if rk is not None:
+        ring = [rk.transpose(0, 2, 1, 3), rv.transpose(0, 2, 1, 3),
+                bias_ring.reshape(B, 1, -1)]
+    return ops, ring
+
+
+def _finish(outs, B, Hq, D, S, W, return_mass):
+    """(out [B, Hq, D], mass [B, S+W] | None) from the kernel outputs."""
+    out = outs[0].reshape(B, Hq, D)
+    if not return_mass:
+        return out, None
+    mass = outs[1].reshape(B, outs[1].shape[1], -1)[..., :S]
+    if W:
+        mass = jnp.concatenate([mass, outs[2][:, :, 0]], axis=-1)
+    return out, mass.sum(axis=1)             # sum over kv heads -> [B, S+W]
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "group", "block_s",
@@ -169,88 +280,42 @@ def decode_attn_pallas(q, k, k_scale, k_zero, v, v_scale, v_zero, bias_main,
     Ring (optional): rk/rv [B, W, Hkv, D] full precision, bias_ring
     [B, W]; pass None/None/None for W == 0.
 
+    The cache block is a whole number of quant groups whose K scales
+    fill whole sublanes (`blocking.tile`); a store that no such block
+    divides is padded here with masked rows.
+
     Returns (out [B, Hq, D] in q.dtype,
              mass [B, S+W] f32 if return_mass else None) with `mass`
     aligned to `cache.materialize` / `cache.accumulate_scores` ordering.
     """
     B, Hq, D = q.shape
     S, Hkv = k.shape[1], k.shape[2]
-    Gq = Hq // Hkv
     W = rk.shape[1] if rk is not None else 0
-    unit = group if bits < 16 else 1
-    bs = pick_block(S, unit, block_s)
-    n_main = S // bs
-    gpb = bs // group if bits < 16 else 0
-    n_grid = n_main + (1 if W else 0)
-    S_tot = S + W
+    bs, S_pad = tile(S, 8 * group if bits < 16 else 8, block_s)
+    n_main = S_pad // bs
+    pad = S_pad - S
 
-    qh = q.reshape(B, Hkv, Gq, D)
-    kh = k.transpose(0, 2, 1, 3)              # [B, Hkv, S, Dp]
-    vh = v.transpose(0, 2, 1, 3)
-
-    def main_idx(b, h, s):
-        return (b, h, jnp.minimum(s, n_main - 1), 0)
-
-    def main_idx3(b, h, s):
-        return (b, h, jnp.minimum(s, n_main - 1))
-
-    def bias_idx(b, h, s):
-        return (b, jnp.minimum(s, n_main - 1))
-
-    operands = [qh, kh]
-    in_specs = [
-        pl.BlockSpec((1, 1, Gq, D), lambda b, h, s: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, bs, kh.shape[-1]), main_idx),
-    ]
+    operands, ring = _side_operands(q, rk, rv, bias_ring, Hkv)
+    operands.append(pad_rows(k, pad).transpose(0, 2, 1, 3))  # [B,Hkv,S,Dp]
     if bits < 16:
-        operands += [k_scale.transpose(0, 2, 1, 3),
-                     k_zero.transpose(0, 2, 1, 3)]
-        in_specs += [pl.BlockSpec((1, 1, gpb, D), main_idx)] * 2
-    operands.append(vh)
-    in_specs.append(pl.BlockSpec((1, 1, bs, vh.shape[-1]), main_idx))
+        operands += [pad_rows(x, pad // group).transpose(0, 2, 1, 3)
+                     for x in (k_scale, k_zero)]
+    operands.append(pad_rows(v, pad).transpose(0, 2, 1, 3))
     if bits < 16:
-        operands += [v_scale.transpose(0, 2, 1), v_zero.transpose(0, 2, 1)]
-        in_specs += [pl.BlockSpec((1, 1, bs), main_idx3)] * 2
-    operands.append(bias_main)
-    in_specs.append(pl.BlockSpec((1, bs), bias_idx))
-    if W:
-        operands += [rk.transpose(0, 2, 1, 3), rv.transpose(0, 2, 1, 3),
-                     bias_ring]
-        in_specs += [pl.BlockSpec((1, 1, W, D), lambda b, h, s: (b, h, 0, 0)),
-                     pl.BlockSpec((1, 1, W, D), lambda b, h, s: (b, h, 0, 0)),
-                     pl.BlockSpec((1, W), lambda b, h, s: (b, 0))]
+        operands += [pad_rows(x, pad).transpose(0, 2, 1)[..., None]
+                     for x in (v_scale, v_zero)]
+    operands.append(pad_rows(bias_main, pad, NEG_INF).reshape(B, n_main, 1,
+                                                              bs))
 
-    out_shape = [jax.ShapeDtypeStruct((B, Hkv, Gq, D), q.dtype)]
-    out_specs = [pl.BlockSpec((1, 1, Gq, D), lambda b, h, s: (b, h, 0, 0))]
-    if return_mass:
-        out_shape.append(jax.ShapeDtypeStruct((B, Hkv, S_tot), jnp.float32))
-        out_specs.append(pl.BlockSpec((1, 1, S_tot),
-                                      lambda b, h, s: (b, h, 0)))
+    def idx(kind):
+        if kind == "bias":
+            return lambda b, h, s: (b, jnp.minimum(s, n_main - 1), 0, 0)
+        return lambda b, h, s: (b, h, jnp.minimum(s, n_main - 1), 0)
 
-    scratch = [
-        pltpu.VMEM((Gq, 1), jnp.float32),
-        pltpu.VMEM((Gq, 1), jnp.float32),
-        pltpu.VMEM((Gq, D), jnp.float32),
-    ]
-    if return_mass:
-        scratch.append(pltpu.VMEM((Gq, S_tot), jnp.float32))
-
-    outs = pl.pallas_call(
-        functools.partial(_kernel, bits=bits, D=D, group=group, block_s=bs,
-                          n_main=n_main, ring_w=W, return_mass=return_mass,
-                          compute_dtype=compute_dtype),
-        grid=(B, Hkv, n_grid),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(*operands)
-
-    out = outs[0].reshape(B, Hq, D)
-    if return_mass:
-        return out, outs[1].sum(axis=1)       # sum over kv heads -> [B, S+W]
-    return out, None
+    call = _call(B, Hkv, Hq // Hkv, D, n_main, bs, W, q.dtype, bits=bits,
+                 group=group, return_mass=return_mass,
+                 compute_dtype=compute_dtype, interpret=interpret, idx=idx)
+    return _finish(call(*operands, *ring), B, Hq, D, S, W, return_mass)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "group", "return_mass",
@@ -272,105 +337,41 @@ def decode_attn_paged_pallas(q, block_tbl, pk, pk_scale, pk_zero, pv,
     grid step (b, h, s) DMAs pool block ``block_tbl[b, s]``. Unmapped
     entries (-1) are clamped to block 0 here; the `bias_main
     [B, n_max*bl]` validity bias masks those positions, so the clamped
-    reads never contribute.
+    reads never contribute. A pool block spans its array's trailing dims,
+    so any block length is legal on the chip.
 
     q [B, Hq, D]; ring/bias/out exactly as `decode_attn_pallas`.
     Returns (out [B, Hq, D], mass [B, S+W] | None)."""
     B, Hq, D = q.shape
-    nb, bl, Hkv = pk.shape[0], pk.shape[1], pk.shape[2]
-    Gq = Hq // Hkv
+    bl, Hkv = pk.shape[1], pk.shape[2]
     n_max = block_tbl.shape[1]
     S = n_max * bl
     assert bias_main.shape == (B, S), (bias_main.shape, B, S)
     if bits < 16:
         assert bl % group == 0, (bl, group)
-    gpb = bl // group if bits < 16 else 0
     W = rk.shape[1] if rk is not None else 0
-    n_grid = n_max + (1 if W else 0)
-    S_tot = S + W
 
-    qh = q.reshape(B, Hkv, Gq, D)
-    kh = pk.transpose(0, 2, 1, 3)              # [nb, Hkv, bl, Dp]
-    vh = pv.transpose(0, 2, 1, 3)
+    operands, ring = _side_operands(q, rk, rv, bias_ring, Hkv)
+    operands.append(pk.transpose(0, 2, 1, 3))         # [nb, Hkv, bl, Dp]
+    if bits < 16:
+        operands += [x.transpose(0, 2, 1, 3) for x in (pk_scale, pk_zero)]
+    operands.append(pv.transpose(0, 2, 1, 3))
+    if bits < 16:
+        operands += [x.transpose(0, 2, 1)[..., None]
+                     for x in (pv_scale, pv_zero)]
+    operands.append(bias_main.reshape(B, n_max, 1, bl))
     tbl = jnp.maximum(block_tbl, 0).astype(jnp.int32)
 
-    def pool_idx(b, h, s, t):
-        return (t[b, jnp.minimum(s, n_max - 1)], h, 0, 0)
+    def idx(kind):
+        if kind == "bias":
+            return lambda b, h, s, t: (b, jnp.minimum(s, n_max - 1), 0, 0)
+        return lambda b, h, s, t: (t[b, jnp.minimum(s, n_max - 1)], h, 0, 0)
 
-    def pool_idx3(b, h, s, t):
-        return (t[b, jnp.minimum(s, n_max - 1)], h, 0)
-
-    def bias_idx(b, h, s, t):
-        return (b, jnp.minimum(s, n_max - 1))
-
-    operands = [qh, kh]
-    in_specs = [
-        pl.BlockSpec((1, 1, Gq, D), lambda b, h, s, t: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, bl, kh.shape[-1]), pool_idx),
-    ]
-    if bits < 16:
-        operands += [pk_scale.transpose(0, 2, 1, 3),
-                     pk_zero.transpose(0, 2, 1, 3)]
-        in_specs += [pl.BlockSpec((1, 1, gpb, D), pool_idx)] * 2
-    operands.append(vh)
-    in_specs.append(pl.BlockSpec((1, 1, bl, vh.shape[-1]), pool_idx))
-    if bits < 16:
-        operands += [pv_scale.transpose(0, 2, 1), pv_zero.transpose(0, 2, 1)]
-        in_specs += [pl.BlockSpec((1, 1, bl), pool_idx3)] * 2
-    operands.append(bias_main)
-    in_specs.append(pl.BlockSpec((1, bl), bias_idx))
-    if W:
-        operands += [rk.transpose(0, 2, 1, 3), rv.transpose(0, 2, 1, 3),
-                     bias_ring]
-        in_specs += [
-            pl.BlockSpec((1, 1, W, D), lambda b, h, s, t: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, W, D), lambda b, h, s, t: (b, h, 0, 0)),
-            pl.BlockSpec((1, W), lambda b, h, s, t: (b, 0)),
-        ]
-
-    out_shape = [jax.ShapeDtypeStruct((B, Hkv, Gq, D), q.dtype)]
-    out_specs = [pl.BlockSpec((1, 1, Gq, D), lambda b, h, s, t: (b, h, 0, 0))]
-    if return_mass:
-        out_shape.append(jax.ShapeDtypeStruct((B, Hkv, S_tot), jnp.float32))
-        out_specs.append(pl.BlockSpec((1, 1, S_tot),
-                                      lambda b, h, s, t: (b, h, 0)))
-
-    scratch = [
-        pltpu.VMEM((Gq, 1), jnp.float32),
-        pltpu.VMEM((Gq, 1), jnp.float32),
-        pltpu.VMEM((Gq, D), jnp.float32),
-    ]
-    if return_mass:
-        scratch.append(pltpu.VMEM((Gq, S_tot), jnp.float32))
-
-    body = functools.partial(_kernel, bits=bits, D=D, group=group,
-                             block_s=bl, n_main=n_max, ring_w=W,
-                             return_mass=return_mass,
-                             compute_dtype=compute_dtype)
-
-    def kernel(tbl_ref, *refs):
-        # the table is only consumed by the index maps; the body is the
-        # same online-softmax kernel as the dense-grid variant
-        del tbl_ref
-        body(*refs)
-
-    outs = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(B, Hkv, n_grid),
-            in_specs=in_specs,
-            out_specs=out_specs,
-            scratch_shapes=scratch,
-        ),
-        out_shape=out_shape,
-        interpret=interpret,
-    )(tbl, *operands)
-
-    out = outs[0].reshape(B, Hq, D)
-    if return_mass:
-        return out, outs[1].sum(axis=1)
-    return out, None
+    call = _call(B, Hkv, Hq // Hkv, D, n_max, bl, W, q.dtype, bits=bits,
+                 group=group, return_mass=return_mass,
+                 compute_dtype=compute_dtype, interpret=interpret, idx=idx,
+                 prefetch=1)
+    return _finish(call(tbl, *operands, *ring), B, Hq, D, S, W, return_mass)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "group", "block_s",

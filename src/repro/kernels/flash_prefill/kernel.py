@@ -143,13 +143,13 @@ def _verify_kernel(q_ref, k_ref, v_ref, kvp_ref, bias_ref, qp_ref, out_ref,
     q = q_ref[0, 0].astype(jnp.float32)            # [bq, D]
     k = k_ref[0, 0].astype(jnp.float32)            # [bk, D]
     v = v_ref[0, 0].astype(jnp.float32)
-    qp = qp_ref[0]                                 # [bq] int32
-    kvp = kvp_ref[0]                               # [bk] int32
-    bias = bias_ref[0]                             # [bk] f32
-    s = (q @ k.T) * scale + bias[None, :]          # [bq, bk]
-    ok = kvp[None, :] <= qp[:, None]
+    qp = qp_ref[0]                                 # [bq, 1] int32
+    kvp = kvp_ref[0, 0]                            # [1, bk] int32
+    bias = bias_ref[0, 0]                          # [1, bk] f32
+    s = (q @ k.T) * scale + bias                   # [bq, bk]
+    ok = kvp <= qp
     if window > 0:
-        ok = jnp.logical_and(ok, kvp[None, :] > qp[:, None] - window)
+        ok = jnp.logical_and(ok, kvp > qp - window)
     s = jnp.where(ok, s, NEG_INF)
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
@@ -179,20 +179,23 @@ def flash_verify_pallas(q, k, v, kv_pos, bias, q_pos, *, window: int = 0,
     Gq = Hq // Hkv
     bk = min(bk, Tk)
     assert Tk % bk == 0, (Tk, bk)
+    n_k = Tk // bk
     qh = q.transpose(0, 2, 1, 3)                   # [B, Hq, L, D]
     kh = k.transpose(0, 2, 1, 3)                   # [B, Hkv, Tk, D]
     vh = v.transpose(0, 2, 1, 3)
+    # per-key rows ride on a unit axis and query positions are a column,
+    # so every block's last two dims span the array's (TPU tiling rule)
     out = pl.pallas_call(
         functools.partial(_verify_kernel, bq=L, bk=bk, window=window,
                           scale=1.0 / math.sqrt(D)),
-        grid=(B, Hq, Tk // bk),
+        grid=(B, Hq, n_k),
         in_specs=[
             pl.BlockSpec((1, 1, L, D), lambda b, h, j: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, bk, D), lambda b, h, j: (b, h // Gq, j, 0)),
             pl.BlockSpec((1, 1, bk, D), lambda b, h, j: (b, h // Gq, j, 0)),
-            pl.BlockSpec((1, bk), lambda b, h, j: (b, j)),
-            pl.BlockSpec((1, bk), lambda b, h, j: (b, j)),
-            pl.BlockSpec((1, L), lambda b, h, j: (b, 0)),
+            pl.BlockSpec((1, 1, 1, bk), lambda b, h, j: (b, j, 0, 0)),
+            pl.BlockSpec((1, 1, 1, bk), lambda b, h, j: (b, j, 0, 0)),
+            pl.BlockSpec((1, L, 1), lambda b, h, j: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, L, D), lambda b, h, j: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hq, L, D), q.dtype),
@@ -202,8 +205,9 @@ def flash_verify_pallas(q, k, v, kv_pos, bias, q_pos, *, window: int = 0,
             pltpu.VMEM((L, D), jnp.float32),
         ],
         interpret=interpret,
-    )(qh, kh, vh, kv_pos.astype(jnp.int32), bias.astype(jnp.float32),
-      q_pos.astype(jnp.int32))
+    )(qh, kh, vh, kv_pos.astype(jnp.int32).reshape(B, n_k, 1, bk),
+      bias.astype(jnp.float32).reshape(B, n_k, 1, bk),
+      q_pos.astype(jnp.int32).reshape(B, L, 1))
     return out.transpose(0, 2, 1, 3)
 
 

@@ -21,9 +21,11 @@ from repro.core.policy import presets
 from repro.nn import model as M
 from repro.obs import Metrics, Tracer, write_metrics_json
 from repro.serving import Engine, Request
+from repro.utils import init_compile_cache
 
 
 def main() -> None:
+    init_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")

@@ -21,9 +21,11 @@ from repro.nn import model as M
 from repro.nn import sharding as shd
 from repro.optim import cosine_schedule, wsd_schedule
 from repro.train.loop import make_train_step
+from repro.utils import init_compile_cache
 
 
 def main() -> None:
+    init_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=100)

@@ -230,12 +230,16 @@ def resolve_use_kernels(flag: Optional[bool]) -> bool:
     return bool(flag)
 
 
-def _kernel_supported(lc, spec: CacheSpec) -> bool:
-    """Shapes the fused kernel can tile; everything else takes the oracle."""
+def _check_kernel_tiles(lc, spec: CacheSpec) -> None:
+    """The fused kernel tiles whole quant groups of a packed store; a
+    cache it cannot tile raises rather than quietly taking the oracle,
+    so a run that resolved to the kernel never measures the oracle."""
     S = lc.scores.shape[1]
-    if spec.quantized:
-        return S % spec.group == 0 and spec.bits in (2, 4, 8)
-    return True
+    if spec.quantized and (spec.bits not in (2, 4, 8) or S % spec.group):
+        raise ValueError(
+            f"the fused decode kernel cannot tile this cache: bits="
+            f"{spec.bits}, main store of {S} rows in {spec.group}-row "
+            f"groups")
 
 
 def decode_attention(
@@ -263,7 +267,8 @@ def decode_attention(
         in_win = kv_positions > (q_pos[:, None] - window)
         bias = bias + jnp.where(in_win, 0.0, NEG_INF)
 
-    if resolve_use_kernels(use_kernels) and _kernel_supported(lc, spec):
+    if resolve_use_kernels(use_kernels):
+        _check_kernel_tiles(lc, spec)
         from repro.kernels.decode_qattn import ops as dq_ops
         quant = spec.quantized
         # the mass statistic costs a [Gq, S+W] probability scratch and a

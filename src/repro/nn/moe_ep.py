@@ -20,7 +20,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 Array = jax.Array
@@ -90,11 +89,11 @@ def moe_apply_expert_parallel(
                            capacity_factor=capacity_factor,
                            ep_axis=ep_axis, n_experts=E)
     expert_spec = P(ep_axis)     # shard dim 0 (experts)
-    smapped = shard_map(
+    smapped = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(), expert_spec, expert_spec, expert_spec, dp_spec),
         out_specs=dp_spec,
-        check_rep=False,
+        check_vma=False,
     )
     x2 = x.reshape(B * T, Dm)
     y = smapped(p["router"], p["gate"], p["up"], p["down"], x2)

@@ -1,6 +1,7 @@
 """Small shared utilities: PRNG splitting by path, tree helpers, dtypes."""
 from __future__ import annotations
 
+import os
 from typing import Any
 
 import jax
@@ -71,3 +72,23 @@ def assert_no_nans(tree: Any, where: str = "") -> None:
                 raise AssertionError(
                     f"non-finite values in {jax.tree_util.keystr(path)} {where}"
                 )
+
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def init_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Called at the start of each entry point's `main()`, never at import.
+    Where `JAX_COMPILATION_CACHE_DIR` is set, JAX already reads it and
+    nothing is set here. Otherwise the cache goes to `.jax_cache` at the
+    root of the checkout (gitignored): a fixed path, so a later run finds
+    what an earlier one compiled."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -141,6 +141,20 @@ def test_decode_attention_kernel_sliding_window():
                                atol=2e-5)
 
 
+def test_decode_attention_explicit_kernels_refuse_untileable_store():
+    """A quantized store that is not a whole number of groups cannot be
+    tiled by the fused kernel: the kernel path raises instead of quietly
+    measuring the oracle."""
+    B, H, D, W = 1, 2, 32, 8
+    spec = CacheSpec(budget=0, window=W, bits=2, group=W, policy="streaming")
+    lc = C.init_layer_kv(spec, B, 44, H, D, jnp.float32)
+    assert lc.scores.shape[1] % spec.group
+    q = jax.random.normal(jax.random.key(2), (B, 1, H, D), jnp.float32)
+    with pytest.raises(ValueError, match="cannot tile"):
+        A.decode_attention(q, lc, spec, dtype=jnp.float32, use_kernels=True,
+                           interpret=True)
+
+
 # ---------------------------------------------------------------------------
 # End to end: generate_continuous, kernels on == kernels off
 # ---------------------------------------------------------------------------

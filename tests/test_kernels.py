@@ -146,8 +146,10 @@ def test_decode_attn_fused_ring_mass_matches_ref(bits, W):
 
 
 def test_decode_attn_fused_block_snapping():
-    """Odd main-store lengths snap the cache block down to a divisor
-    (quantized stores tile in group units)."""
+    """Odd main-store lengths still tile: a store that fits the target is
+    one block (a block spanning its array is always legal on the chip),
+    longer ones split into even sublane-aligned blocks (quantized stores
+    in whole groups whose K scales fill whole sublanes)."""
     B, S, Hkv, Gq, D, G = 1, 96, 1, 2, 32, 32
     keys = jax.random.split(jax.random.key(1), 3)
     k = jax.random.normal(keys[0], (B, S, Hkv, D), jnp.float32)
@@ -156,8 +158,8 @@ def test_decode_attn_fused_block_snapping():
     bias = jnp.zeros((B, S))
     kk, ks, kz = kq_ref.kquant_ref(k, 4, G)
     vv, vs, vz = kq_ref.vquant_ref(v, 4)
-    assert dq_kernel.pick_block(S, G, 512) == 96
-    assert dq_kernel.pick_block(S, 1, 64) == 48
+    assert dq_kernel.tile(S, 8 * G, 512) == (96, 96)
+    assert dq_kernel.tile(S, 8, 64) == (48, 96)
     o_ref, m_ref = dq_ref.decode_attn_ref(
         q, kk, ks, kz, vv, vs, vz, bias, None, None, None, bits=4, group=G)
     o_ker, m_ker = dq_kernel.decode_attn_pallas(
