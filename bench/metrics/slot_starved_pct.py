@@ -1,0 +1,14 @@
+"""slot_starved_pct: share of slot-steps in which a slot sat free while
+requests waited in the queue (`ContinuousGenerationResult.starved_share`,
+counted by the scheduler at every decode step), over the window's jobs
+weighted by their decode steps. None where the program does not count
+it."""
+
+
+def read(ctx):
+    jobs = [res for _, res in ctx.jobs]
+    steps = sum(res.decode_steps for res in jobs)
+    if not steps or not all(hasattr(res, "starved_share") for res in jobs):
+        return None
+    return 100.0 * sum(res.starved_share * res.decode_steps
+                       for res in jobs) / steps

@@ -23,8 +23,8 @@ def main() -> None:
         ("Fig1: inference-rate improvement", fig1_rates.run),
         ("Fig2: end-to-end engine throughput (survey §5/§6)",
          fig2_throughput.run),
-        ("Serving: continuous-batching metrics snapshot "
-         "(BENCH_serving.json)", serving_continuous.run),
+        ("Serving: continuous-batching decode rates",
+         serving_continuous.run),
         ("Kernels: micro-benchmarks (interpret mode)", kernels_micro.run),
         ("Roofline: dry-run derived terms (single-pod)", roofline.run),
     ]

@@ -62,7 +62,6 @@ blocks read-only, so the report shows the warm/cold prefill-time ratio
 from __future__ import annotations
 
 import argparse
-import os
 import sys
 from dataclasses import dataclass
 
@@ -73,18 +72,13 @@ try:                              # package import (python -m benchmarks.run)
 except ImportError:               # direct script run from benchmarks/
     from common import bench_model
 from repro.core.policy import presets
-from repro.obs import Metrics, write_metrics_json
+from repro.obs import Metrics
 from repro.serving import Engine, Request
 from repro.utils import human_bytes
 
 BUCKETS = (64, 128)
 SLOTS = 4
 MAX_NEW_CAP = 24
-
-# gitignored artifact dir: the snapshot lands next to the other
-# benchmark JSON dumps regardless of the caller's cwd
-DEFAULT_METRICS_JSON = os.path.join(
-    os.path.dirname(os.path.abspath(__file__)), "BENCH_serving.json")
 
 
 @dataclass
@@ -551,28 +545,18 @@ def tiered_report(window=32, *, block_len=16, slots=4, requests=8,
 
 def run() -> str:
     """Driver entry (`python -m benchmarks.run`): a small continuous-
-    batching run per policy with a live `Metrics` registry; the snapshot
-    lands in benchmarks/BENCH_serving.json so successive PRs accumulate
-    a comparable perf trajectory (same schema serve.py --metrics-json
-    writes)."""
+    batching run per policy, timed on the host clock (a toy model's
+    overheads, not chip numbers; `bench/` measures speed on the chip)."""
     cfg, params = bench_model(n_layers=2, d_model=128, train_steps=0)
     requests = make_requests(cfg.vocab_size, 8, BUCKETS, MAX_NEW_CAP)
-    metrics = Metrics()
-    policies = ("full", "kivi2")
     lines = []
-    for pname in policies:
+    for pname in ("full", "kivi2"):
         pol = presets(budget=64, window=16)[pname]
         res = run_continuous(cfg, params, pol, requests, SLOTS, BUCKETS,
-                             warmup=True, paged=True, metrics=metrics)
+                             warmup=True, paged=True)
         lines.append(f"{pname}: {res.decode_tokens_per_s:.1f} decode "
                      f"tok/s, occupancy {res.occupancy:.2f}, "
                      f"ttft {res.ttft_mean_s * 1e3:.1f} ms")
-    payload = write_metrics_json(metrics, DEFAULT_METRICS_JSON, extra={
-        "workload": {"requests": len(requests), "buckets": list(BUCKETS),
-                     "slots": SLOTS, "paged": True,
-                     "policies": list(policies)}})
-    lines.append(f"{len(payload['metrics'])} instruments -> "
-                 f"{DEFAULT_METRICS_JSON}")
     return "\n".join(lines)
 
 
@@ -629,11 +613,6 @@ def main() -> int:
     ap.add_argument("--json", default="",
                     help="write every computed report to PATH as JSON "
                          "(machine-readable mirror of the stdout tables)")
-    ap.add_argument("--metrics-json", default=DEFAULT_METRICS_JSON,
-                    metavar="PATH",
-                    help="write the head-to-head runs' live Metrics "
-                         "registry snapshot here (same schema as serve.py "
-                         "--metrics-json; '' disables)")
     args = ap.parse_args()
     use_kernels = {"auto": None, "on": True, "off": False}[args.use_kernels]
 
@@ -800,16 +779,6 @@ def main() -> int:
                   f"retries, {r['degrades']} degrades, audit "
                   f"{'clean' if r['audit_clean'] else 'DIRTY'}")
 
-    if args.metrics_json:
-        # written before --check so a failed gate still leaves the data
-        payload = write_metrics_json(metrics, args.metrics_json, extra={
-            "workload": {"requests": len(requests),
-                         "buckets": list(BUCKETS), "slots": args.slots,
-                         "paged": args.paged,
-                         "policies": [r.policy for r in rows]}})
-        print(f"wrote metrics snapshot ({len(payload['metrics'])} "
-              f"instruments) to {args.metrics_json}")
-
     if args.json:
         # written before --check so a failed gate still leaves the data
         import dataclasses
@@ -840,7 +809,7 @@ def main() -> int:
             "prefix_sharing": pfx,
             "overload": over,
             "tiering": tiered,
-            # same registry the --metrics-json snapshot serializes
+            # the head-to-head runs' live registry
             "metrics": metrics.snapshot(),
         })
         with open(args.json, "w") as f:

@@ -150,8 +150,8 @@ def main() -> None:
     ap.add_argument("--metrics-json", default="", metavar="PATH",
                     help="dump the run's metrics registry snapshot "
                          "(tok/s, TTFT/inter-token histograms, pool/tier/"
-                         "preemption counters) as JSON to PATH — same "
-                         "schema as the benchmarks' BENCH_serving.json")
+                         "preemption and slot-state counters) as JSON "
+                         "to PATH")
     ap.add_argument("--audit-every", type=int, default=0,
                     help="run the pool invariant audit (allocator "
                          "refcounts vs slot block tables vs prefix "

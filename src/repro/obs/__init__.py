@@ -10,10 +10,11 @@ falsy singletons) and costs one attribute check per event site.
 from repro.obs.metrics import (Counter, Gauge, Histogram, Metrics,
                                NULL_METRICS, NullMetrics,
                                write_metrics_json)
-from repro.obs.trace import (NULL_TRACER, NullTracer, Span, Tracer)
+from repro.obs.trace import (NULL_TRACER, NullTracer, Phases, Span,
+                             Tracer)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "Metrics", "NullMetrics",
     "NULL_METRICS", "write_metrics_json",
-    "NullTracer", "NULL_TRACER", "Span", "Tracer",
+    "NullTracer", "NULL_TRACER", "Phases", "Span", "Tracer",
 ]

@@ -96,7 +96,7 @@ class Histogram:
 
 class Metrics:
     """Get-or-create registry. Instrument names are free-form dotted
-    strings (``pool.free_frac``, ``request.ttft_s``); re-registering a
+    strings (``slots.drain_steps``, ``request.ttft_s``); re-registering a
     name with a different instrument type is an error, not a shadow."""
 
     def __init__(self) -> None:
@@ -192,8 +192,8 @@ NULL_METRICS = NullMetrics()
 def write_metrics_json(metrics, path: str, *, extra: Optional[dict] = None
                        ) -> dict:
     """Serialize a registry snapshot to `path` in the one standard
-    layout shared by ``serve.py --metrics-json`` and the benchmarks'
-    ``BENCH_serving.json``. Returns the written payload."""
+    layout ``serve.py --metrics-json`` writes. Returns the written
+    payload."""
     payload = {"schema": SCHEMA, "metrics": metrics.snapshot()}
     if extra:
         payload.update(extra)

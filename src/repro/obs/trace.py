@@ -16,6 +16,10 @@ Timestamps are absolute ``time.perf_counter()`` seconds — the same
 clock the `Scheduler` injects as its default ``clock=`` — so scheduler
 lifecycle times and engine phase times land on one comparable axis;
 export rebases them to the tracer's creation time in microseconds.
+`Tracer.anchor` ties that axis to another recorder's clock: it reads
+``perf_counter`` inside a context manager the caller supplies (a
+profiler annotation), so one offset maps every event onto the other
+recorder's timeline.
 
 Lanes (Chrome ``tid``): 0 is the engine loop; ``slot + 1`` is the lane
 of batch slot ``slot``. Export emits ``M`` metadata records naming
@@ -33,7 +37,7 @@ from __future__ import annotations
 import json
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, ContextManager, Dict, List, Optional
 
 _DEFAULT_CAPACITY = 65536
 
@@ -71,6 +75,37 @@ class Span:
         return False
 
 
+class Phases:
+    """The consecutive phases of a serving loop on one lane.
+
+    ``to(name)`` ends the running phase and starts `name`; ``to(None)``
+    ends it and starts nothing. Every phase's seconds add to
+    ``totals[name]`` whether or not tracing is on (the loop reports its
+    fetch seconds from there), and under a live tracer each phase is
+    also one complete event. A wait nested in a phase is a phase of its
+    own: ``prev = ph.to("fetch"); ...; ph.to(prev)``."""
+
+    __slots__ = ("_trace", "tid", "name", "t0", "totals")
+
+    def __init__(self, trace, tid: int = 0) -> None:
+        self._trace = trace
+        self.tid = tid
+        self.name: Optional[str] = None
+        self.t0 = 0.0
+        self.totals: Dict[str, float] = {}
+
+    def to(self, name: Optional[str]) -> Optional[str]:
+        """End the running phase, start `name`; returns the ended one."""
+        t = time.perf_counter()
+        prev = self.name
+        if prev is not None:
+            self.totals[prev] = self.totals.get(prev, 0.0) + (t - self.t0)
+            if self._trace:
+                self._trace.complete(prev, self.t0, t, tid=self.tid)
+        self.name, self.t0 = name, t
+        return prev
+
+
 class Tracer:
     """Bounded ring of trace events with Chrome-JSON export.
 
@@ -90,6 +125,7 @@ class Tracer:
         self.t0 = time.perf_counter()
         self._ring: deque = deque(maxlen=self.capacity)
         self.dropped = 0
+        self.anchors: Dict[str, float] = {}
 
     # -- emit ------------------------------------------------------------
     now = staticmethod(time.perf_counter)
@@ -122,6 +158,17 @@ class Tracer:
     def span(self, name: str, *, tid: int = 0,
              args: Optional[dict] = None) -> Span:
         return Span(self, name, tid, args)
+
+    def anchor(self, annotation: Callable[[str], ContextManager],
+               name: str = "obs_anchor") -> float:
+        """Read ``perf_counter`` inside ``annotation(name)`` — e.g.
+        ``jax.profiler.TraceAnnotation`` — and keep it in
+        ``anchors[name]``: the annotation's start on the other clock
+        minus this reading is the offset between the two clocks."""
+        with annotation(name):
+            t = time.perf_counter()
+        self.anchors[name] = t
+        return t
 
     # -- inspect ---------------------------------------------------------
     def __bool__(self) -> bool:

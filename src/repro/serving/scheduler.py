@@ -203,6 +203,12 @@ class Scheduler:
         self.results: List[RequestResult] = []
         self._decode_steps = 0
         self._active_slot_steps = 0
+        # why the other slot-steps did no useful work: mid-admission,
+        # free while requests waited (starved), free with the queue
+        # empty (drain, the job's tail)
+        self.prefill_slot_steps = 0
+        self.starved_slot_steps = 0
+        self.drain_slot_steps = 0
         self._admit_seq = itertools.count()
         self.n_preemptions = 0        # fleet totals (per-request counts
         self.n_retries = 0            # land on RequestResult)
@@ -667,8 +673,22 @@ class Scheduler:
 
     # ---- fleet accounting ------------------------------------------------
     def note_decode_step(self) -> None:
+        """Count one decode step and the state of every slot in it."""
         self._decode_steps += 1
-        self._active_slot_steps += len(self.active_slots())
+        n_active = n_prefill = 0
+        for s in self._slots:
+            if s is not None:
+                if s.prefilling:
+                    n_prefill += 1
+                else:
+                    n_active += 1
+        n_free = self.n_slots - n_active - n_prefill
+        self._active_slot_steps += n_active
+        self.prefill_slot_steps += n_prefill
+        if self._queue:
+            self.starved_slot_steps += n_free
+        else:
+            self.drain_slot_steps += n_free
 
     @property
     def decode_steps(self) -> int:
@@ -677,5 +697,22 @@ class Scheduler:
     @property
     def occupancy(self) -> float:
         """Mean fraction of slots doing useful work per decode step."""
-        return self._active_slot_steps / max(1, self._decode_steps
-                                             * self.n_slots)
+        return self._share(self._active_slot_steps)
+
+    @property
+    def prefill_share(self) -> float:
+        """Mean fraction of slots mid-admission (PREFILLING) per step."""
+        return self._share(self.prefill_slot_steps)
+
+    @property
+    def starved_share(self) -> float:
+        """Mean fraction of slots free while requests waited, per step."""
+        return self._share(self.starved_slot_steps)
+
+    @property
+    def drain_share(self) -> float:
+        """Mean fraction of slots free with the queue empty, per step."""
+        return self._share(self.drain_slot_steps)
+
+    def _share(self, slot_steps: int) -> float:
+        return slot_steps / max(1, self._decode_steps * self.n_slots)

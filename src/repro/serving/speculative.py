@@ -62,6 +62,7 @@ import numpy as np
 
 from repro.core import paging as paging_lib
 from repro.core.cache import CacheSpec
+from repro.obs import Phases
 from repro.serving.scheduler import Request
 
 Array = jax.Array
@@ -344,6 +345,9 @@ def generate_continuous_spec(eng, requests: Sequence[Union[Request,
     prefill_s = 0.0
     decode_tokens = 0
     clean = set(range(eng.slots))
+    # token fetches are timed always (the result's fetch seconds) and
+    # emitted as `fetch` spans under a live tracer
+    phases = Phases(eng.trace)
 
     def reset_slot(i: int) -> None:
         nonlocal cache, dcache
@@ -462,8 +466,11 @@ def generate_continuous_spec(eng, requests: Sequence[Union[Request,
                 st.replay = [int(t) for t in req.emitted_prefix[:-1]]
                 st.resumed = True
                 return True
+            prev = phases.to("fetch")
             # kvlint: ok(host-sync: admission prefill's first token — once per admitted request, not per round)
-            if not record(slot, int(jax.device_get(tok)[0]), count=False):
+            tok_i = int(jax.device_get(tok)[0])
+            phases.to(prev)
+            if not record(slot, tok_i, count=False):
                 return True
             # 1-token request: retired immediately, refill the slot
 
@@ -508,22 +515,20 @@ def generate_continuous_spec(eng, requests: Sequence[Union[Request,
         for i in range(eng.slots):
             admit_into(i)
 
-    # per-round telemetry: pre-bound instruments, host mirrors only
+    # per-round telemetry: host mirrors only
     trace = eng.trace
-    mx = eng.metrics
-    g_free = mx.gauge("pool.free_frac")
-    g_active = mx.gauge("slots.active")
-    c_iters = mx.counter("engine.loop_iters")
+    n_iters = 0
     loop_t0 = time.perf_counter()
     prefill_at_loop = prefill_s
+    fetch_at_loop = phases.totals.get("fetch", 0.0)
     while True:
         it_t0 = time.perf_counter()
-        if mx:
-            g_active.set(len(sched.active_slots()))
-            c_iters.inc()
-            if eng.paged:
-                g_free.set(eng.block_allocator.available
-                           / max(eng.pool_blocks, 1))
+        n_iters += 1
+        if trace:
+            trace.counter("slots", dict(
+                active=len(sched.active_slots()),
+                prefilling=len(sched.prefilling_slots()),
+                free=len(sched.free_slots())))
         if eng.chunked_prefill and adm is None:
             adm, dt0 = eng._start_admission_timed(sched)
             prefill_s += dt0
@@ -548,8 +553,11 @@ def generate_continuous_spec(eng, requests: Sequence[Union[Request,
                     st0.replay = [int(t) for t in req0.emitted_prefix[:-1]]
                     st0.resumed = True
                 else:
+                    phases.to("fetch")
                     # kvlint: ok(host-sync: chunk-admitted first token — once per admission, not per round)
-                    record(slot0, int(jax.device_get(ftok)[0]), count=False)
+                    tok0 = int(jax.device_get(ftok)[0])
+                    phases.to(None)
+                    record(slot0, tok0, count=False)
                 active = sched.active_slots()
         if preempt_due:
             # forced preemptions (tests): fire at the given dispatch round
@@ -634,8 +642,10 @@ def generate_continuous_spec(eng, requests: Sequence[Union[Request,
             tok_dev, dcache = eng._draft_decode(
                 eng.params, dcache, jnp.asarray(feed)[:, None],
                 jnp.asarray(mask), kd)
+            phases.to("fetch")
             # kvlint: ok(host-sync: draft tokens feed the host-built verify batch — draft rounds are synchronous by design)
             toks = np.asarray(tok_dev)
+            phases.to(None)
             for s in participating:
                 if not mask[s]:
                     continue
@@ -718,8 +728,10 @@ def generate_continuous_spec(eng, requests: Sequence[Union[Request,
             if trace:
                 trace.complete("round", it_t0,
                                args=dict(kind="plain", active=len(active)))
+            phases.to("fetch")
             # kvlint: ok(host-sync: plain-decode fallback round — the token builds the next feed host-side)
             toks = np.asarray(tok_dev)
+            phases.to(None)
             for s in active:
                 st = slot_state[s]
                 tmirror.append(s, 1)
@@ -751,10 +763,12 @@ def generate_continuous_spec(eng, requests: Sequence[Union[Request,
         if trace:
             trace.complete("round", it_t0,
                            args=dict(kind="verify", active=len(active)))
+        phases.to("fetch")
         # kvlint: ok(host-sync: verify results drive host-side acceptance mirroring — the round is synchronous by design)
         y = np.asarray(y_dev)
         # kvlint: ok(host-sync: verify results drive host-side acceptance mirroring — the round is synchronous by design)
         acc = np.asarray(acc_dev)
+        phases.to(None)
 
         # device-side acceptance/rollback already happened inside
         # verify_step; mirror it host-side and roll the drafter back
@@ -809,9 +823,12 @@ def generate_continuous_spec(eng, requests: Sequence[Union[Request,
                     if not sched.pending or not admit_into(i):
                         break
 
-    decode_s = (time.perf_counter() - loop_t0) - (prefill_s - prefill_at_loop)
+    loop_s = time.perf_counter() - loop_t0
+    decode_s = loop_s - (prefill_s - prefill_at_loop)
     if eng.paged:
         eng._run_audit(sched)    # every pool block accounted for, or raise
     return eng._continuous_result(
         sched, cache, prefill_s=prefill_s, decode_s=decode_s,
-        decode_tokens=decode_tokens, spec_stats=stats)
+        decode_tokens=decode_tokens, loop_iters=n_iters, loop_s=loop_s,
+        fetch_s=phases.totals.get("fetch", 0.0) - fetch_at_loop,
+        spec_stats=stats)

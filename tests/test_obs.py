@@ -13,8 +13,7 @@ Tier-2 (own CI job); the pinned contracts:
   * a forced-preemption + tiering run's exported trace contains the
     preempt -> spill -> restore chain in causal timestamp order;
   * Metrics: get-or-create typing, histogram bucketing, and the one
-    serialized schema `serve.py --metrics-json` and the benchmarks'
-    BENCH_serving.json share.
+    serialized schema `serve.py --metrics-json` writes.
 """
 import json
 import time
@@ -27,7 +26,7 @@ from repro.configs.base import get_config, reduced
 from repro.core.policy import presets
 from repro.nn import model as M
 from repro.obs import (NULL_TRACER, Metrics, NullMetrics, NullTracer,
-                       Tracer, write_metrics_json)
+                       Phases, Tracer, write_metrics_json)
 from repro.serving import Engine, Request
 
 # ---------------------------------------------------------------------------
@@ -94,6 +93,47 @@ def test_chrome_export_schema(tmp_path):
     assert inst["s"] == "t" and inst["args"] == dict(a=1)
     assert comp["dur"] >= 0
     assert ctr["args"] == dict(free=3, active=1)
+
+
+def test_phases_time_always_and_emit_only_when_tracing():
+    """`Phases` sums every phase's seconds with tracing off as on; a
+    live tracer also gets one complete event per phase, contiguous."""
+    for trace in (NULL_TRACER, Tracer()):
+        ph = Phases(trace)
+        assert ph.to("admit") is None
+        time.sleep(0.002)
+        assert ph.to("fetch") == "admit"
+        time.sleep(0.002)
+        prev = ph.to("fetch")           # a wait nested in a phase
+        assert prev == "fetch" and ph.to(prev) == "fetch"
+        ph.to(None)
+        assert ph.totals["admit"] >= 0.002 and ph.totals["fetch"] >= 0.002
+        evs = trace.events()
+        if trace:
+            assert [e[1] for e in evs] == ["admit", "fetch", "fetch",
+                                           "fetch"]
+            for a, b in zip(evs, evs[1:]):
+                assert a[3] + a[4] == pytest.approx(b[3], abs=1e-9)
+        else:
+            assert evs == []
+
+
+def test_anchor_reads_the_clock_inside_the_annotation():
+    """`anchor` reads perf_counter inside the caller's context manager,
+    which is what ties the tracer's clock to the annotation's."""
+    import contextlib
+    seen = []
+
+    @contextlib.contextmanager
+    def annotation(name):
+        seen.append((name, time.perf_counter()))
+        yield
+        seen.append((name, time.perf_counter()))
+
+    tr = Tracer()
+    t = tr.anchor(annotation)
+    assert [n for n, _ in seen] == ["obs_anchor", "obs_anchor"]
+    assert seen[0][1] <= t <= seen[1][1] and tr.anchors == {"obs_anchor": t}
 
 
 # ---------------------------------------------------------------------------
@@ -185,14 +225,58 @@ def test_trace_on_streams_bit_identical(small_model, pname, paged):
     assert _tokens(on) == _tokens(off)
     names = {e[1] for e in tr.events()}
     assert {"submit", "admit", "first_token", "prefill", "step",
-            "request"} <= names
-    assert mx.counter("engine.loop_iters").value > 0
+            "request", "setup", "finish", "slots"} <= names
+    assert mx.counter("engine.loop_iters").value == on.loop_iters > 0
     assert mx.histogram("request.ttft_s").count == 3
     snap = mx.snapshot()
     assert snap["requests.completed"] == 3 and snap["requests.failed"] == 0
+    # 3 requests on 2 slots: one waits while both slots decode, and a
+    # slot frees before the last request does
+    slot_steps = on.decode_steps * 2
+    assert snap["slots.drain_steps"] > 0
+    assert (snap["slots.prefill_steps"] + snap["slots.starved_steps"]
+            + snap["slots.drain_steps"]) == pytest.approx(
+                slot_steps * (1 - on.occupancy))
+    assert on.drain_share == snap["slots.drain_steps"] / slot_steps
     if paged:
         assert "pool" in names          # per-iteration counter track
-        assert 0.0 <= snap["pool.free_frac"] <= 1.0
+
+
+def test_trace_on_chunked_streams_bit_identical(small_model):
+    """Chunked admission with the tracer on: the same streams, every
+    iteration's `step` span holds its leaf phases, and the loop's host
+    seconds from the spans (steps less their fetches) are the result's
+    own (`loop_seconds - fetch_seconds`)."""
+    cfg, params = small_model
+    pol = presets(budget=32, window=8)["full"]
+    kw = dict(prompt_len=32, max_new=10, slots=2, buckets=(32,), seed=0,
+              chunked_prefill=True, chunk_len=16)
+    reqs = lambda: _requests(cfg, 3, seed=1)
+    off = Engine(cfg, params, pol, **kw).generate_continuous(reqs())
+    tr = Tracer()
+    on = Engine(cfg, params, pol, tracer=tr, **kw).generate_continuous(
+        reqs())
+    assert _tokens(on) == _tokens(off)
+    assert on.decode_steps == off.decode_steps
+    assert (on.prefill_share, on.starved_share, on.drain_share) == (
+        off.prefill_share, off.starved_share, off.drain_share)
+    evs = [e for e in tr.events() if e[0] == "X" and e[2] == 0]
+    steps = [e for e in evs if e[1] == "step"]
+    assert len(steps) == on.loop_iters
+    fetch = 0.0
+    for e in evs:
+        if e[1] in ("admit", "dispatch", "fetch", "record"):
+            assert any(s[3] <= e[3] and e[3] + e[4] <= s[3] + s[4]
+                       for s in steps), e
+            fetch += e[4] if e[1] == "fetch" else 0.0
+    assert fetch == pytest.approx(on.fetch_seconds, rel=1e-6)
+    assert sum(s[4] for s in steps) == pytest.approx(on.loop_seconds,
+                                                     rel=0.05)
+    assert {e[1] for e in evs} >= {"setup", "finish", "admit", "dispatch",
+                                   "fetch", "record"}
+    chunk_uids = {e[5]["uid"] for e in tr.events()
+                  if e[1] == "prefill_chunk"}
+    assert chunk_uids == {r.uid for r in on.results}
 
 
 @pytest.mark.parametrize("pname,paged", [("full", False), ("kivi2", True)])

@@ -78,6 +78,36 @@ def test_scheduler_occupancy_accounting():
     assert sched.occupancy == pytest.approx(0.5)
 
 
+def test_scheduler_slot_state_counts():
+    """Every slot-step of a scripted admit/prefill/retire sequence is
+    active, prefilling, starved (free, queue waiting) or drain (free,
+    queue empty), counted exactly."""
+    sched = Scheduler((16,), n_slots=3, clock=_FakeClock())
+    for _ in range(3):
+        sched.submit(_req(16, max_new=4))
+    sched.admit_next(0)
+    sched.begin_prefill(1)
+    sched.note_decode_step()        # active 0, prefilling 1, starved 2
+    sched.note_decode_step()
+    sched.finish_prefill(1)
+    sched.begin_prefill(2)          # queue now empty
+    sched.note_decode_step()        # active 0 1, prefilling 2
+    sched.finish_prefill(2)
+    sched.retire(0, "length")
+    sched.note_decode_step()        # active 1 2, drain 0
+    sched.retire(1, "length")
+    sched.note_decode_step()        # active 2, drain 0 1
+    assert sched.decode_steps == 5
+    assert (sched.prefill_slot_steps, sched.starved_slot_steps,
+            sched.drain_slot_steps) == (3, 2, 3)
+    assert sched.occupancy == pytest.approx(7 / 15)
+    assert sched.prefill_share == pytest.approx(3 / 15)
+    assert sched.starved_share == pytest.approx(2 / 15)
+    assert sched.drain_share == pytest.approx(3 / 15)
+    assert (sched.occupancy + sched.prefill_share + sched.starved_share
+            + sched.drain_share) == pytest.approx(1.0)
+
+
 def test_scheduler_chunked_admission_interleaves_resident_decode():
     """PREFILLING lifecycle (fake clock): while slot 1 streams its
     prompt chunks, the resident slot 0 keeps recording tokens — and the
