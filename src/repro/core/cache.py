@@ -414,25 +414,47 @@ def reset_slot(stacked: LayerKV, slot_idx, *, batch_axis: int = 1) -> LayerKV:
 def append_token_dense(
     lc: LayerKV, spec: CacheSpec, k_new: Array, v_new: Array,
     key: Optional[Array] = None, mask: Optional[Array] = None,
+    layer: Optional[Array] = None,
 ) -> LayerKV:
     """k_new/v_new: [B, H, D] (post-RoPE). Fixed-budget eviction append.
     mask: optional [B] bool — rows where it is False are left untouched
     (ragged multi-token appends: speculative drafts, per-row segment
-    tails)."""
-    S = lc.k.shape[1]
+    tails).
+
+    layer: optional int32 scalar — `lc.k`/`lc.v` are then the
+    layer-stacked stores [L, B, S, H, D] (every other leaf is this
+    layer's), and each row is scattered into layer `layer` in place, so
+    the stack is neither sliced nor rebuilt."""
+    S = lc.slot_pos.shape[1]
     cap = jnp.minimum(lc.budget, S)
     full = lc.length >= cap
     victim = select_victim(lc, spec, key)
     slot = jnp.where(full, victim, lc.length)
+    if layer is None:
+        k = _put_rows_masked(lc.k, slot, k_new.astype(lc.k.dtype), mask)
+        v = _put_rows_masked(lc.v, slot, v_new.astype(lc.v.dtype), mask)
+    else:
+        k = _put_layer_rows(lc.k, layer, slot, k_new, mask)
+        v = _put_layer_rows(lc.v, layer, slot, v_new, mask)
     return lc._replace(
-        k=_put_rows_masked(lc.k, slot, k_new.astype(lc.k.dtype), mask),
-        v=_put_rows_masked(lc.v, slot, v_new.astype(lc.v.dtype), mask),
+        k=k, v=v,
         scores=_put_rows_masked(lc.scores, slot,
                                 jnp.zeros(lc.scores.shape[:1]), mask),
         slot_pos=_put_rows_masked(lc.slot_pos, slot, lc.pos, mask),
         length=_sel_rows(mask, jnp.minimum(lc.length + 1, cap), lc.length),
         pos=_sel_rows(mask, lc.pos + 1, lc.pos),
     )
+
+
+def _put_layer_rows(stack: Array, layer: Array, slot: Array, val: Array,
+                    mask: Optional[Array]) -> Array:
+    """stack [L, B, S, ...]: write val[b] at [layer, b, slot[b]] in place.
+    A row gated off by `mask` is sent past the store's end and dropped."""
+    B, S = stack.shape[1], stack.shape[2]
+    if mask is not None:
+        slot = jnp.where(mask, slot, S)
+    return stack.at[layer, jnp.arange(B), slot].set(
+        val.astype(stack.dtype), mode="drop")
 
 
 # ---------------------------------------------------------------------------
@@ -549,7 +571,12 @@ def append_token_quantized(
 
 
 def append_token(lc, spec: CacheSpec, k_new: Array, v_new: Array,
-                 key: Optional[Array] = None, mask: Optional[Array] = None):
+                 key: Optional[Array] = None, mask: Optional[Array] = None,
+                 layer: Optional[Array] = None):
+    """One token's append to any store; `layer` as in
+    `append_token_dense` (dense stores only)."""
+    if layer is not None:
+        return append_token_dense(lc, spec, k_new, v_new, key, mask, layer)
     if not isinstance(lc, LayerKV):
         # paged store (core/paging.py): same eviction/flush semantics,
         # writes routed through the block table

@@ -39,3 +39,14 @@ def pad_rows(x, n: int, fill=0):
         return x
     return jnp.pad(x, [(0, 0), (0, n)] + [(0, 0)] * (x.ndim - 2),
                    constant_values=fill)
+
+
+def reads_in_place(kv_heads: int, head_dim: int) -> bool:
+    """Whether a dense `[.., S, Hkv, D]` store can be read where it lies.
+
+    The TPU lays such an array out row-major, each row's `(Hkv, D)` whole
+    in its tiles, only when D fills whole 128-lane rows and Hkv whole
+    sublane tiles (a multiple of 8, or 2 or 4, which have tiles of their
+    own). Any other store XLA lays out with S further in, and a kernel
+    that reads rows would have it relaid out in full at every call."""
+    return head_dim % 128 == 0 and (kv_heads % 8 == 0 or kv_heads in (2, 4))

@@ -19,17 +19,18 @@ def decode_attention_fused(q, k, k_scale, k_zero, v, v_scale, v_zero,
                            group: int, block_s: int = 512,
                            return_mass: bool = False,
                            compute_dtype=None,
-                           interpret: Optional[bool] = None):
+                           interpret: Optional[bool] = None, layer=None):
     """Fused [main store | residual ring] decode attention.
 
-    See `kernel.decode_attn_pallas` for shapes. Returns (out, mass|None)."""
+    See `kernel.decode_attn_pallas` for shapes (and `layer`, for a dense
+    layer stack). Returns (out, mass|None)."""
     import jax.numpy as jnp
     return kernel.decode_attn_pallas(
         q, k, k_scale, k_zero, v, v_scale, v_zero, bias_main, rk, rv,
         bias_ring, bits=bits, group=group, block_s=block_s,
         return_mass=return_mass,
         compute_dtype=jnp.float32 if compute_dtype is None else compute_dtype,
-        interpret=resolve_interpret(interpret))
+        interpret=resolve_interpret(interpret), layer=layer)
 
 
 def decode_attention_paged(q, block_tbl, pk, pk_scale, pk_zero, pv, pv_scale,

@@ -246,10 +246,15 @@ def decode_attention(
     q: Array, lc: LayerKV, spec: CacheSpec, *, window: int = 0,
     dtype=jnp.bfloat16, q_pos: Optional[Array] = None,
     use_kernels: Optional[bool] = None, interpret: Optional[bool] = None,
+    layer: Optional[Array] = None,
 ):
     """q: [B, 1, Hq, D] rotated at absolute position `q_pos` [B]
     (defaults to lc.pos - 1: the append-first decode convention, so the
     token attends to itself through the cache).
+
+    layer: optional int32 scalar — `lc.k`/`lc.v` are the layer-stacked
+    dense stores (`cache.append_token_dense`); the kernel attends layer
+    `layer` where it lies, the oracle slices it out.
 
     Returns (out [B, 1, Hq, D], attn_mass [B, S+W]) — mass aligned with
     `cache.materialize` ordering for `cache.accumulate_scores`.
@@ -301,11 +306,13 @@ def decode_attention(
                 bias[:, S:] if W else None,
                 bits=spec.bits if quant else 16, group=spec.group,
                 return_mass=want_mass, compute_dtype=dtype,
-                interpret=interpret)
+                interpret=interpret, layer=layer)
         if mass is None:
             mass = jnp.zeros((q.shape[0], S + W), jnp.float32)
         return out[:, None].astype(dtype), mass
 
+    if layer is not None:
+        lc = lc._replace(k=lc.k[layer], v=lc.v[layer])
     k, v = kvcache.materialize_kv(lc, spec, dtype)
     out, mass = gqa_attention(
         q, k, v, causal=False, kv_positions=kv_positions, kv_bias=bias,

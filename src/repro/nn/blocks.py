@@ -264,13 +264,18 @@ def block_verify(p: dict, x: Array, cfg, spec: CacheSpec, lc,
 
 def block_decode(p: dict, x: Array, cfg, kind: str, spec: CacheSpec,
                  cache_piece, *, key: Optional[Array] = None, memory_kv=None,
-                 append_mask: Optional[Array] = None):
+                 append_mask: Optional[Array] = None,
+                 layer: Optional[Array] = None):
     """x: [B, 1, d_model]. Returns (x, new cache piece).
 
     append_mask: optional [B] bool — rows where it is False leave the
     cache untouched (their attention output is still computed, and
     discarded by the caller). Used by the speculative drafter, whose
-    per-slot draft depths are ragged."""
+    per-slot draft depths are ragged.
+
+    layer: optional int32 scalar — the piece's k/v are the layer-stacked
+    dense stores, written and attended at layer `layer` in place
+    (`cache.append_token_dense`)."""
     h = L.rmsnorm(p["norm1"], x, cfg.norm_eps)
     if kind == "attn":
         lc: LayerKV = cache_piece
@@ -278,10 +283,11 @@ def block_decode(p: dict, x: Array, cfg, kind: str, spec: CacheSpec,
         q, k_new, v_new = attn.qkv(p["attn"], h, cfg, pos)
         # append-first: the new token attends to itself through the cache
         lc = kvcache.append_token(lc, spec, k_new[:, 0], v_new[:, 0], key=key,
-                                  mask=append_mask)
+                                  mask=append_mask, layer=layer)
         o, mass = attn.decode_attention(
             q, lc, spec, window=cfg.sliding_window, dtype=cfg.dtype,
-            q_pos=pos[:, 0], use_kernels=getattr(cfg, "use_kernels", None))
+            q_pos=pos[:, 0], use_kernels=getattr(cfg, "use_kernels", None),
+            layer=layer)
         lc = kvcache.accumulate_scores(lc, spec, mass, key=key,
                                        gate=append_mask)
         B = x.shape[0]

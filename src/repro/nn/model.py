@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from repro.core import cache as kvcache
 from repro.core.cache import CacheSpec, LayerKV
+from repro.kernels.blocking import reads_in_place
 from repro.nn import blocks as B
 from repro.nn import layers as L
 from repro.nn import ssm as ssm_lib
@@ -436,6 +437,19 @@ def prefill_from_kv(cfg, spec: CacheSpec, ks: Array, vs: Array, *,
 # ---------------------------------------------------------------------------
 
 
+def decode_in_place(cache: ModelCache, spec: CacheSpec) -> bool:
+    """Whether `decode_step` updates this cache's attention store in
+    place: a dense (bits 16), non-paged store whose layout lets the
+    kernel read it where it lies (`blocking.reads_in_place`: whole
+    128-lane heads, more than one KV head). Its K/V layer stacks are
+    then carried through the layer loop, each layer's new rows are
+    scattered into the stack, and the decode kernel reads the layer where
+    it lies — the stack is never sliced out, transposed or rebuilt.
+    Other stores pass through the loop layer by layer."""
+    return (isinstance(cache.attn, LayerKV) and not spec.quantized
+            and reads_in_place(*cache.attn.k.shape[-2:]))
+
+
 def decode_step(params, cfg, cache: ModelCache, token: Array,
                 spec: CacheSpec, *, key: Optional[Array] = None,
                 append_mask: Optional[Array] = None):
@@ -443,7 +457,11 @@ def decode_step(params, cfg, cache: ModelCache, token: Array,
 
     append_mask: optional [B] bool — rows where it is False leave the
     cache untouched (ragged speculative drafting; attention-only archs,
-    SSM state cannot be row-gated against its own step)."""
+    SSM state cannot be row-gated against its own step).
+
+    A dense store is updated in place (`decode_in_place`): with the
+    cache donated, the step's only writes to it are one row per slot and
+    layer."""
     x = L.embed(params["embed"], token)
     sb, n_sb, kinds = sb_layout(cfg)
     aps, sps = attn_positions(cfg), ssm_positions(cfg)
@@ -456,9 +474,18 @@ def decode_step(params, cfg, cache: ModelCache, token: Array,
         n_sb, max(len(aps), 1))
 
     has_cross = cache.cross_k is not None
+    in_place = decode_in_place(cache, spec)
+    attn_xs, kv = cache.attn, ()
+    if in_place:
+        # [n_sb, nA, B, S, H, D] -> [n_sb * nA, B, S, H, D]: a bitcast
+        kv_shape = cache.attn.k.shape
+        kv = tuple(t.reshape(-1, *kv_shape[2:])
+                   for t in (cache.attn.k, cache.attn.v))
+        attn_xs = cache.attn._replace(k=None, v=None)
 
-    def body(x, xs):
-        p_sb, a_sl, s_sl, ks, ck, cv = xs
+    def body(carry, xs):
+        x, kv = carry
+        p_sb, a_sl, s_sl, ks, ck, cv, i_sb = xs
         attn_pieces, ssm_pieces = [], []
         for i in range(sb):
             mkv = None
@@ -467,10 +494,18 @@ def decode_step(params, cfg, cache: ModelCache, token: Array,
             if kinds[i][0] == "attn":
                 j = aps.index(i)
                 piece = jax.tree.map(lambda t: t[j], a_sl)
+                layer = None
+                if in_place:
+                    layer = i_sb * len(aps) + j
+                    piece = piece._replace(k=kv[0], v=kv[1])
                 x, piece = B.block_decode(p_sb[f"sub{i}"], x, cfg, "attn",
                                           spec, piece, key=ks[j],
                                           memory_kv=mkv,
-                                          append_mask=append_mask)
+                                          append_mask=append_mask,
+                                          layer=layer)
+                if in_place:
+                    kv = (piece.k, piece.v)
+                    piece = piece._replace(k=None, v=None)
                 attn_pieces.append(piece)
             else:
                 j = sps.index(i)
@@ -482,13 +517,16 @@ def decode_step(params, cfg, cache: ModelCache, token: Array,
              if attn_pieces else None)
         s = (jax.tree.map(lambda *xs: jnp.stack(xs), *ssm_pieces)
              if ssm_pieces else None)
-        return x, (a, s)
+        return (x, kv), (a, s)
 
     cross_k = cache.cross_k if has_cross else jnp.zeros((n_sb, 0))
     cross_v = cache.cross_v if has_cross else jnp.zeros((n_sb, 0))
-    x, (attn_c, ssm_c) = jax.lax.scan(
-        body, x, (params["blocks"], cache.attn, cache.ssm, keys,
-                  cross_k, cross_v))
+    (x, kv), (attn_c, ssm_c) = jax.lax.scan(
+        body, (x, kv), (params["blocks"], attn_xs, cache.ssm, keys,
+                        cross_k, cross_v, jnp.arange(n_sb)))
+    if in_place:
+        attn_c = attn_c._replace(k=kv[0].reshape(kv_shape),
+                                 v=kv[1].reshape(kv_shape))
     logits = _logits(params, cfg, x)[:, 0]
     return logits, ModelCache(attn_c, ssm_c, cache.cross_k, cache.cross_v,
                               cache.cross_bias)

@@ -107,6 +107,9 @@ class ContinuousGenerationResult:
     loop_iters: int = 0
     loop_seconds: float = 0.0
     fetch_seconds: float = 0.0
+    # decode steps that updated a dense store in place
+    # (`model.decode_in_place`); 0 for quantized, paged and speculative
+    inplace_steps: int = 0
     pool_blocks: int = 0          # paged runs only: reserved pool size,
     pool_block_bytes: int = 0     # bytes one block pins across layers,
     pool_peak_blocks: int = 0     # high-water allocated blocks
@@ -869,6 +872,7 @@ class Engine:
     def _continuous_result(self, sched, cache, *, prefill_s: float,
                            decode_s: float, decode_tokens: int,
                            loop_iters: int, loop_s: float, fetch_s: float,
+                           inplace_steps: int = 0,
                            spec_stats=None) -> "ContinuousGenerationResult":
         """Post-run accounting shared by the plain and speculative
         continuous loops (bytes, ratios, latency aggregates) — one copy
@@ -935,6 +939,7 @@ class Engine:
             loop_iters=loop_iters,
             loop_seconds=loop_s,
             fetch_seconds=fetch_s,
+            inplace_steps=inplace_steps,
             spec=spec_stats,
             prefix=prefix_stats,
             tier=tier_stats,
@@ -963,6 +968,7 @@ class Engine:
         mx.counter("engine.decode_steps").inc(res.decode_steps)
         mx.counter("engine.decode_tokens").inc(res.decode_tokens)
         mx.counter("engine.loop_iters").inc(res.loop_iters)
+        mx.counter("decode.inplace_steps").inc(res.inplace_steps)
         mx.counter("slots.prefill_steps").inc(sched.prefill_slot_steps)
         mx.counter("slots.starved_steps").inc(sched.starved_slot_steps)
         mx.counter("slots.drain_steps").inc(sched.drain_slot_steps)
@@ -2274,7 +2280,10 @@ class Engine:
             sched, cache, prefill_s=prefill_s, decode_s=decode_s,
             decode_tokens=decode_tokens, loop_iters=n_iters,
             loop_s=t_end - loop_t0,
-            fetch_s=phases.totals.get("fetch", 0.0) - fetch_at_loop)
+            fetch_s=phases.totals.get("fetch", 0.0) - fetch_at_loop,
+            # every decode dispatch runs `self._decode` on this store
+            inplace_steps=(sched.decode_steps
+                           if M.decode_in_place(cache, self.spec) else 0))
         if trace:
             trace.complete("finish", t_end)
         return res

@@ -227,3 +227,101 @@ def test_engine_use_kernels_flag_plumbs_to_config(f32_model):
     assert eng.cfg.use_kernels is True
     eng = Engine(cfg, params, pol, prompt_len=32, max_new=4, slots=2)
     assert eng.cfg.use_kernels is None
+
+
+# ---------------------------------------------------------------------------
+# In-place decode of a dense store
+# ---------------------------------------------------------------------------
+
+
+def _scan_decode_step(params, cfg, cache, token, spec, key, append_mask):
+    """The layer loop that passes each layer's piece through the scan:
+    sliced out of the stacked store, appended and attended layer by
+    layer, and restacked (uniform attention archs; the keys split as
+    `M.decode_step` splits them)."""
+    from repro.nn import blocks as Bk
+    from repro.nn import layers as Ly
+    x = Ly.embed(params["embed"], token)
+    n_sb = cfg.num_layers
+    keys = jax.random.split(key, n_sb).reshape(n_sb, 1)
+
+    def body(x, xs):
+        p_sb, a_sl, ks = xs
+        piece = jax.tree.map(lambda t: t[0], a_sl)
+        x, piece = Bk.block_decode(p_sb["sub0"], x, cfg, "attn", spec,
+                                   piece, key=ks[0], append_mask=append_mask)
+        return x, jax.tree.map(lambda t: t[None], piece)
+
+    x, attn_c = jax.lax.scan(body, x, (params["blocks"], cache.attn, keys))
+    return M._logits(params, cfg, x)[:, 0], cache._replace(attn=attn_c)
+
+
+@pytest.fixture(scope="module")
+def aligned_model():
+    """f32 weights, 2 KV heads of 128: a dense store the kernel can read
+    where it lies (`blocking.reads_in_place`)."""
+    cfg = reduced(get_config("paper-llama-7b"), num_layers=2, head_dim=128)
+    return cfg, M.init_params(jax.random.key(0), cfg)
+
+
+@pytest.mark.parametrize("pname", ["full", "h2o"])
+def test_inplace_decode_step_matches_layer_scan(aligned_model, pname):
+    """Kernels on (interpret mode): the in-place `decode_step` over a
+    dense store gives the same logits, tokens and cache as the layer
+    scan, step after step, with ragged slots (an `append_mask` holds
+    rows back) and, for h2o, evictions once its 16-row store fills."""
+    import dataclasses
+    cfg, params = aligned_model
+    cfg = dataclasses.replace(cfg, use_kernels=True)
+    spec = presets(budget=16, window=8)[pname].spec
+    assert M.decode_in_place(M.init_cache(cfg, spec, 1, 8), spec)
+    B, steps = 3, 22
+    cache = M.init_cache(cfg, spec, B, 24)
+    # kvlint: ok(jit-donate: a CPU parity test, where donation is a no-op)
+    inplace = jax.jit(lambda c, t, k, m: M.decode_step(
+        params, cfg, c, t, spec, key=k, append_mask=m))
+    # kvlint: ok(jit-donate: a CPU parity test, where donation is a no-op)
+    scan = jax.jit(lambda c, t, k, m: _scan_decode_step(
+        params, cfg, c, t, spec, k, m))
+    rng = np.random.default_rng(0)
+    tok = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, 1)), jnp.int32)
+    c_in = c_sc = cache
+    for t in range(steps):
+        mask = jnp.asarray([True, t % 3 != 1, t % 2 == 0])
+        key = jax.random.key(t)
+        lg_in, c_in = inplace(c_in, tok, key, mask)
+        lg_sc, c_sc = scan(c_sc, tok, key, mask)
+        np.testing.assert_array_equal(np.asarray(lg_in), np.asarray(lg_sc),
+                                      err_msg=f"{pname} step {t}")
+        tok = jnp.argmax(lg_in, -1).astype(jnp.int32)[:, None]
+    for name, a, b in zip(C.LayerKV._fields, c_in.attn, c_sc.attn):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=f"{pname} cache.{name}")
+    lengths = np.asarray(c_in.attn.length)[0, 0]
+    assert len(set(lengths.tolist())) > 1, lengths      # ragged slots
+
+
+@pytest.mark.parametrize("pname,paged,aligned,in_place", [
+    ("full", False, True, True), ("h2o", False, True, True),
+    ("kivi2", False, True, False), ("full", True, True, False),
+    ("full", False, False, False),
+])
+def test_engine_counts_inplace_decode_steps(f32_model, aligned_model, pname,
+                                            paged, aligned, in_place):
+    """`decode.inplace_steps` counts every decode step of an engine over
+    a dense store of whole 128-lane heads, and none over a quantized, a
+    paged, or a dense store of 64-wide heads."""
+    from repro.obs import Metrics
+    cfg, params = aligned_model if aligned else f32_model
+    kw = dict(prompt_len=32, max_new=4, slots=2, buckets=(32,))
+    if paged:
+        kw.update(paged=True, block_len=8)
+    mx = Metrics()
+    rng = np.random.default_rng(2)
+    reqs = [Request(tokens=rng.integers(0, cfg.vocab_size, 32).astype(
+        np.int32), max_new=4) for _ in range(3)]
+    res = Engine(cfg, params, presets(budget=16, window=8)[pname],
+                 metrics=mx, **kw).generate_continuous(reqs)
+    assert res.decode_steps > 0
+    assert res.inplace_steps == (res.decode_steps if in_place else 0)
+    assert mx.snapshot()["decode.inplace_steps"] == res.inplace_steps

@@ -171,6 +171,80 @@ def test_decode_attn_fused_block_snapping():
                                atol=2e-4)
 
 
+def _stack_case(L, B, S, Hkv, Gq, D, W, seed=0):
+    """A dense layer stack [L, B, S, Hkv, D] (+ ring), q, and a bias with
+    ragged lengths (row 0 shorter) and random holes."""
+    ks = jax.random.split(jax.random.key(seed), 7)
+    k = jax.random.normal(ks[0], (L, B, S, Hkv, D), jnp.float32)
+    v = jax.random.normal(ks[1], (L, B, S, Hkv, D), jnp.float32)
+    q = jax.random.normal(ks[2], (B, Hkv * Gq, D), jnp.float32)
+    lengths = jnp.asarray([S * 2 // 5] + [S] * (B - 1))
+    live = ((jnp.arange(S)[None] < lengths[:, None])
+            & (jax.random.uniform(ks[3], (B, S)) > 0.2))
+    bias = jnp.where(live, 0.0, -1e30)
+    ring = (None, None, None)
+    if W:
+        ring = (jax.random.normal(ks[4], (B, W, Hkv, D), jnp.float32),
+                jax.random.normal(ks[5], (B, W, Hkv, D), jnp.float32),
+                jnp.where(jax.random.uniform(ks[6], (B, W)) < 0.3,
+                          -1e30, 0.0))
+    return q, k, v, bias, ring
+
+
+@pytest.mark.parametrize("W,mass", [(0, False), (0, True), (16, False),
+                                    (16, True)])
+@pytest.mark.parametrize("Hkv,Gq", [(1, 1), (1, 4), (8, 1), (8, 4)])
+def test_decode_attn_layer_stack_matches_ref(Hkv, Gq, W, mass):
+    """The dense store read where it lies: layer `layer` of a
+    [L, B, S, Hkv, D] stack attended in place equals the materialize
+    oracle on that layer, with ragged lengths, a store of 200 rows that
+    no 56-row block divides (the last block overhangs it), with and
+    without the ring and the mass output."""
+    L, B, S, D, layer = 3, 2, 200, 32, 1
+    q, k, v, bias, (rk, rv, rbias) = _stack_case(L, B, S, Hkv, Gq, D, W)
+    assert dq_kernel.tile(S, 8, 64) == (56, 224)
+    o_ref, m_ref = dq_ref.decode_attn_ref(
+        q, k[layer], None, None, v[layer], None, None, bias, rk, rv, rbias,
+        bits=16, group=1)
+    o_ker, m_ker = dq_kernel.decode_attn_pallas(
+        q, k, None, None, v, None, None, bias, rk, rv, rbias, bits=16,
+        group=1, block_s=64, return_mass=mass, interpret=True,
+        layer=jnp.int32(layer))
+    np.testing.assert_allclose(np.asarray(o_ker), np.asarray(o_ref),
+                               atol=2e-5, rtol=2e-5)
+    if mass:
+        np.testing.assert_allclose(np.asarray(m_ker), np.asarray(m_ref),
+                                   atol=2e-5, rtol=2e-5)
+    else:
+        assert m_ker is None
+
+
+def test_decode_attn_layer_stack_reads_only_its_layer():
+    """Every other layer of the stack is NaN: layer j's output stays
+    finite and equal to the oracle, so the kernel reads layer j alone
+    (and the rows past the store, in the overhanging last block, are
+    never let into the softmax)."""
+    L, B, S, Hkv, Gq, D = 4, 2, 200, 8, 4, 32
+    q, k, v, bias, (rk, rv, rbias) = _stack_case(L, B, S, Hkv, Gq, D, 16,
+                                                 seed=3)
+    for j in (0, 3):
+        other = (jnp.arange(L) != j)[:, None, None, None, None]
+        kn, vn = jnp.where(other, jnp.nan, k), jnp.where(other, jnp.nan, v)
+        o_ref, m_ref = dq_ref.decode_attn_ref(
+            q, k[j], None, None, v[j], None, None, bias, rk, rv, rbias,
+            bits=16, group=1)
+        o_ker, m_ker = dq_kernel.decode_attn_pallas(
+            q, kn, None, None, vn, None, None, bias, rk, rv, rbias,
+            bits=16, group=1, block_s=64, return_mass=True, interpret=True,
+            layer=jnp.int32(j))
+        assert np.isfinite(np.asarray(o_ker)).all()
+        assert np.isfinite(np.asarray(m_ker)).all()
+        np.testing.assert_allclose(np.asarray(o_ker), np.asarray(o_ref),
+                                   atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(np.asarray(m_ker), np.asarray(m_ref),
+                                   atol=2e-5, rtol=2e-5)
+
+
 # ---------------------------------------------------------------------------
 # flash_prefill
 # ---------------------------------------------------------------------------
