@@ -23,8 +23,6 @@ import os
 
 import numpy as np
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-
 
 def sample(served: list, seed: int, min_tokens: int) -> list:
     """(request, result) pairs: the longest served one, then others in a
@@ -47,13 +45,10 @@ def sample(served: list, seed: int, min_tokens: int) -> list:
 
 
 def _reference(c):
-    import importlib.util
-    path = os.path.join(HERE, "refs", f"{c.config['reference']}.py")
-    spec = importlib.util.spec_from_file_location(
-        "ref_" + c.config["reference"], path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    from harness import _module
+    name = c.config["reference"]
+    return _module(os.path.join(c.root, "bench", "refs", f"{name}.py"),
+                   "ref_" + name)
 
 
 def _kv(c) -> tuple:

@@ -72,13 +72,13 @@ def main(argv=None) -> int:
         return 2
     c = harness.load_cell(a.workload)
     seeds = [int(s) for s in a.seeds.split(",")]
-    eng = harness.engine(c, weights.make(c.config, seeds[0]))
+    eng = harness.engine(c, weights.make(c, seeds[0]))
     harness.serve_job(eng, traffic.warmup_job(c.mix, c.config["vocab_size"],
                                               seeds[0]))
     out = []
     for s in seeds:
         t = time.perf_counter()
-        r = readings(c, eng, s, lambda k: weights.make(c.config, k))
+        r = readings(c, eng, s, lambda k: weights.make(c, k))
         r["seconds"] = time.perf_counter() - t
         print(json.dumps(r), flush=True)
         out.append(r)

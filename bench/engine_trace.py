@@ -138,7 +138,7 @@ def probe(name: str, seed: int, seconds: float, *, root: str = ROOT,
     if require_tpu and dev.platform != "tpu":
         raise SystemExit(f"{name} needs a TPU; JAX found {dev.platform}")
     init_compile_cache()
-    params = weights.make(c.config, seed)
+    params = weights.make(c, seed)
     eng = harness.engine(c, params)
     harness.serve_job(eng, traffic.warmup_job(c.mix, c.config["vocab_size"],
                                               seed))

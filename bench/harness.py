@@ -6,6 +6,8 @@ metric is a file found by name:
     BENCHMARK.json               the cells, metrics and their bounds
     bench/configs/<config>.json  sizes, source, cuts, the reference's name
     bench/refs/<reference>.py    the plain reference of that architecture
+    bench/archs/<reference>.py   the program side of it: its `ModelConfig`,
+                                 weight tree and work counts
     bench/traffic/<mix>.json     parameters for `traffic.py`
     bench/cells/<workload>.json  engine settings, job size, check limits
     bench/metrics/<metric>.py    `read(ctx)` -> number, or None
@@ -18,6 +20,7 @@ and the window spans the first job's call to the last job's return.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import os
@@ -38,6 +41,7 @@ def _json(*parts):
 def _module(path: str, name: str):
     spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod     # dataclasses look their module up there
     spec.loader.exec_module(mod)
     return mod
 
@@ -50,6 +54,19 @@ class Cell:
     cell: dict           # bench/cells/<name>.json
     mix: dict            # bench/traffic/<traffic>.json
     bench: dict          # the whole BENCHMARK.json
+    root: str = ROOT     # the checkout it was found in
+
+    @functools.cached_property
+    def arch(self):
+        """The configuration's architecture module, loaded once."""
+        return arch(self.config["reference"], self.root)
+
+
+def arch(name: str, root: str = ROOT):
+    """`bench/archs/<name>.py`: `program_config(conf)`,
+    `weight_shapes(conf)` and `work(conf, engine)` of an architecture."""
+    return _module(os.path.join(root, "bench", "archs", f"{name}.py"),
+                   "arch_" + name)
 
 
 def load_cell(name: str, root: str = ROOT) -> Cell:
@@ -63,7 +80,7 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
                 config=_json(root, conf["file"]),
                 cell=_json(bdir, "cells", f"{name}.json"),
                 mix=_json(bdir, "traffic", f"{entry['traffic']}.json"),
-                bench=bench)
+                bench=bench, root=root)
 
 
 def metrics_for(c: Cell, trace: bool) -> list:
@@ -78,18 +95,6 @@ def metrics_for(c: Cell, trace: bool) -> list:
                 else m["moves"] in names)]
 
 
-def model_config(conf: dict):
-    """The program's `ModelConfig` for a configuration file."""
-    import jax.numpy as jnp
-    from repro.configs.base import ModelConfig
-    keys = ("num_layers", "d_model", "num_heads", "num_kv_heads", "d_ff",
-            "vocab_size", "head_dim", "tie_embeddings", "norm_eps",
-            "rope_theta")
-    return ModelConfig(name=conf["name"], arch_type="dense",
-                       source=conf["source"], dtype=jnp.bfloat16,
-                       remat="none", **{k: conf[k] for k in keys})
-
-
 def policy(eng: dict):
     from repro.core.policy import presets
     return presets(budget=eng.get("budget", 0),
@@ -102,14 +107,14 @@ def engine(c: Cell, params):
     e = dict(c.cell["engine"])
     kw = {k: e[k] for k in ("slots", "paged", "block_len", "block_growth",
                             "chunked_prefill", "chunk_len") if k in e}
-    return Engine(model_config(c.config), params, policy(e),
+    return Engine(c.arch.program_config(c.config), params, policy(e),
                   buckets=c.mix["prompt"]["buckets"],
                   max_new=c.mix["output"]["max"], use_kernels=True, **kw)
 
 
 def counts_shape(c: Cell):
-    import counts
-    return counts.Shape.of(c.config, c.cell["engine"])
+    """The work object `counts` sums for this cell."""
+    return c.arch.work(c.config, c.cell["engine"])
 
 
 # ---------------------------------------------------------------------------
@@ -176,7 +181,7 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
         import peaks
         ctx.peaks = peaks.peaks_for(dev.device_kind)
 
-    params = weights.make(c.config, seed)
+    params = weights.make(c, seed)
     eng = engine(c, params)
     vocab = c.config["vocab_size"]
     serve_job(eng, traffic.warmup_job(c.mix, vocab, seed))

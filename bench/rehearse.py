@@ -46,7 +46,7 @@ def rehearse(name: str) -> dict:
             s.shape, s.dtype, sharding=one), tree)
 
     c = harness.load_cell(name)
-    params = weights.abstract(c.config, sharding=one)
+    params = weights.abstract(c, sharding=one)
     eng = harness.engine(c, params)
     cfg, spec = eng.cfg, eng.spec
     lb = jnp.asarray(eng.layer_budgets, jnp.int32)

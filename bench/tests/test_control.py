@@ -21,8 +21,8 @@ def _cell(root, name):
 @pytest.mark.parametrize("name", ["tiny.full.tinymix", "tiny.kivi2.tinymix"])
 def test_control_fails_the_limit_sound_runs_pass(tiny_root, name):
     c = _cell(tiny_root, name)
-    eng = harness.engine(c, weights.make(c.config, SEEDS[0]))
-    got = [control.readings(c, eng, s, lambda k: weights.make(c.config, k))
+    eng = harness.engine(c, weights.make(c, SEEDS[0]))
+    got = [control.readings(c, eng, s, lambda k: weights.make(c, k))
            for s in SEEDS]
     for number, limit in c.cell["check"]["limits"].items():
         assert all(r[f"program.{number}"] <= limit for r in got), got

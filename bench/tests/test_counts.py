@@ -1,20 +1,27 @@
-"""The work counts behind rooflines and MFU, against hand-worked values."""
+"""The work counts behind rooflines and MFU, against hand-worked values:
+the dense decoder's work object, and `counts`' loops over a work object
+of another cache layout."""
 import counts
+import harness
 import pytest
 
-DENSE = counts.Shape(layers=1, d_model=8, heads=2, kv_heads=1, head_dim=4,
-                     d_ff=16, vocab=32)
-KIVI = counts.Shape(layers=2, d_model=8, heads=2, kv_heads=2, head_dim=8,
-                    d_ff=16, vocab=32, kv_bits=2, kv_group=4)
+DENSE_ARCH = harness.arch("dense_decoder")
+DENSE = DENSE_ARCH.Work(layers=1, d_model=8, heads=2, kv_heads=1,
+                        head_dim=4, d_ff=16, vocab=32)
+KIVI = DENSE_ARCH.Work(layers=2, d_model=8, heads=2, kv_heads=2,
+                       head_dim=8, d_ff=16, vocab=32,
+                       store=counts.Store(bits=2, group=4))
 
 
 def test_dense_decode_bytes_and_flops():
     # K and V, 10 rows x 1 head x 4 dims x 2 bytes
-    assert counts.decode_kv_bytes(10, DENSE) == 160
+    assert DENSE.decode_kv_bytes(10) == 160
     # q read + out written: 2 heads x 4 dims x 2 bytes each
-    assert counts.decode_qo_bytes(DENSE) == 32
+    assert DENSE.decode_qo_bytes() == 32
     # QK^T + PV: 4 x pairs x heads x head_dim x layers
-    assert counts.attn_flops(10, DENSE) == 320
+    assert DENSE.attn_flops(10) == 320
+    # the LM head: 2 x d_model x vocab
+    assert DENSE.head_flops() == 512
 
 
 def test_kivi_decode_bytes():
@@ -24,9 +31,9 @@ def test_kivi_decode_bytes():
     vmeta = 2 * 8 * 2 * 4                   # V scale+zero per token/head
     ring = 2 * 2 * 2 * 8 * 2
     assert (codes, kmeta, vmeta, ring) == (64, 256, 128, 128)
-    assert counts.decode_kv_bytes(10, KIVI) == 2 * 576
+    assert KIVI.decode_kv_bytes(10) == 2 * 576
     # a group boundary: the key at position 8 sits alone in the ring
-    assert counts.decode_kv_bytes(9, KIVI) == 2 * (
+    assert KIVI.decode_kv_bytes(9) == 2 * (
         2 * 8 * 2 * 8 * 2 // 8 + 2 * 2 * 2 * 8 * 4 + 2 * 8 * 2 * 4
         + 2 * 1 * 2 * 8 * 2)
 
@@ -55,6 +62,47 @@ def test_decode_and_prefill_need():
         2 * 2 * 2 * 4 * 2 + 2 * 4 * 4 * 2)
 
 
+class _Latent:
+    """A latent-attention layout: one bf16 row of 576 values a position
+    and layer (512 latent + 64 rope), read whole by every head; no K/V
+    split and no head_dim."""
+    layers = 3
+
+    def block_params(self):
+        return 1000
+
+    def attn_flops(self, pairs):
+        return 7 * pairs * self.layers
+
+    def head_flops(self):
+        return 50
+
+    def decode_kv_bytes(self, ctx):
+        return ctx * 576 * 2 * self.layers
+
+    def decode_qo_bytes(self):
+        return 11
+
+    def chunk_io_bytes(self, c0, c):
+        return (c0 + c) * 576 * 2 * self.layers + 13 * c
+
+
+def test_loops_assume_no_kv_layout():
+    reqs = [counts.Served(prompt=4, served=3), counts.Served(prompt=2,
+                                                             served=1)]
+    # forwarded 6 + 2 tokens; pairs (10 + 5 + 6) + 3; served 3 + 1
+    assert counts.model_flops(reqs, _Latent()) == (
+        2 * 1000 * 8 + 7 * 24 * 3 + 50 * 4)
+    # decode steps only for the first request, at contexts 5 and 6
+    f, b = counts.decode_attn_need(reqs, _Latent())
+    assert f == 7 * (5 + 6) * 3
+    assert b == (5 + 6) * 576 * 2 * 3 + 2 * 11
+    # chunks of 2: (0, 2), (2, 2) and (0, 2); pairs 3, 7, 3
+    f, b = counts.prefill_attn_need(reqs, _Latent(), chunk=2)
+    assert f == 7 * (3 + 7 + 3) * 3
+    assert b == (2 + 4 + 2) * 576 * 2 * 3 + 13 * 6
+
+
 def test_roofline_share_names_its_bound():
     pk = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
     share, bound = counts.roofline_share(197e9, 2 * 819e6, 0.004, pk)
@@ -64,12 +112,12 @@ def test_roofline_share_names_its_bound():
 
 
 def test_shape_of_files():
-    s = counts.Shape.of(
+    s = DENSE_ARCH.work(
         dict(num_layers=40, d_model=2304, num_heads=36, num_kv_heads=36,
              head_dim=64, d_ff=5760, vocab_size=122753,
              tie_embeddings=True),
         {"policy": "kivi2", "window": 128})
-    assert (s.kv_bits, s.kv_group) == (2, 128)
+    assert s.store == counts.Store(bits=2, group=128)
     # 2.72B parameters in all: the layer stack plus the tied table
     assert s.block_params() + 122753 * 2304 == pytest.approx(2.72e9,
                                                              rel=0.01)

@@ -44,7 +44,7 @@ def traced_job(tiny_root):
     import weights
     from repro.obs import Tracer
     c = harness.load_cell(CELL, tiny_root)
-    eng = harness.engine(c, weights.make(c.config, SEED))
+    eng = harness.engine(c, weights.make(c, SEED))
     eng.trace = tracer = Tracer()
     reqs = traffic.job(c.mix, c.cell["job_requests"],
                        c.config["vocab_size"], SEED, 0)
