@@ -29,6 +29,22 @@ class MoEConfig:
     router_aux_coef: float = 0.01  # load-balance aux loss
     router_z_coef: float = 1e-3
     capacity_factor: float = 1.25  # expert capacity (E == drop-free)
+    num_shared: int = 0            # shared experts, fused into one MLP of
+                                   # width num_shared * d_expert (DeepSeek)
+    norm_topk: bool = True         # renormalize the top-k weights to sum 1
+    first_dense: int = 0           # leading dense layers of width d_ff
+                                   # (DeepSeek's first_k_dense_replace)
+
+
+@dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling (arXiv:2309.00071), as DeepSeek-V2 applies it."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -62,6 +78,14 @@ class ModelConfig:
     norm_eps: float = 1e-5
     rope_theta: float = 10_000.0
     sliding_window: int = 0        # 0 = full attention
+    # multi-head latent attention (DeepSeek-V2, arXiv:2405.04434): K/V of
+    # every head are expanded from one cached latent of `kv_lora_rank`
+    # plus a shared rope key of `qk_rope_head_dim`; 0 = ordinary GQA
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_yarn: Optional[YarnScaling] = None
     moe: MoEConfig = field(default_factory=MoEConfig)
     ssm: SSMConfig = field(default_factory=SSMConfig)
     # hybrid interleave: 1 attention layer per `attn_layer_period` layers,
@@ -87,12 +111,37 @@ class ModelConfig:
 
     def __post_init__(self):
         if self.head_dim == 0:
-            object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
+            object.__setattr__(self, "head_dim",
+                               self.qk_nope_head_dim + self.qk_rope_head_dim
+                               if self.kv_lora_rank
+                               else self.d_model // self.num_heads)
 
     # ---- derived ---------------------------------------------------------
     @property
     def is_moe(self) -> bool:
         return self.moe.num_experts > 0
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_dim(self) -> int:
+        """Width of one latent row (MLA): latent + rope key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_store_dim(self) -> int:
+        """Lanes a cached latent row takes: `latent_dim` rounded up to the
+        TPU's 128-lane tile with zeros. The tile pads a narrower row to
+        that width in memory anyway, and a row a whole number of tiles
+        wide is laid out row by row, so a kernel reads it in place."""
+        return -(-self.latent_dim // 128) * 128
+
+    @property
+    def lead_layers(self) -> int:
+        """Leading dense layers, held apart from the layer pattern."""
+        return self.moe.first_dense if self.is_moe else 0
 
     @property
     def is_ssm_only(self) -> bool:
@@ -121,10 +170,11 @@ class ModelConfig:
 
     def ffn_kind(self, idx: int) -> str:
         """FFN kind of layer `idx`: "moe" or "dense". Layer period counts
-        from 1 like Jamba (odd layers MoE when period==2)."""
-        if self.is_moe and idx % self.moe.layer_period == (
-            self.moe.layer_period - 1
-        ):
+        from 1 like Jamba (odd layers MoE when period==2), after the
+        leading dense layers."""
+        e = self.moe
+        if (self.is_moe and idx >= e.first_dense
+                and idx % e.layer_period == e.layer_period - 1):
             return "moe"
         return "dense"
 
@@ -139,7 +189,12 @@ class ModelConfig:
             n += self.vocab_size * self.d_model                  # lm head
         for i in range(self.num_layers):
             n += 2 * self.d_model                                # norms
-            if self.layer_kind(i) == "attn":
+            if self.layer_kind(i) == "attn" and self.is_mla:
+                r, h = self.kv_lora_rank, self.num_heads
+                n += self.d_model * (h * self.head_dim + self.latent_dim)
+                n += r + r * h * (self.qk_nope_head_dim + self.v_head_dim)
+                n += h * self.v_head_dim * self.d_model
+            elif self.layer_kind(i) == "attn":
                 hq = self.num_heads * self.head_dim
                 hkv = self.num_kv_heads * self.head_dim
                 n += self.d_model * (hq + 2 * hkv) + hq * self.d_model
@@ -155,7 +210,8 @@ class ModelConfig:
                 n += d_in                                        # gated norm
             if self.ffn_kind(i) == "moe":
                 e = self.moe
-                n += e.num_experts * 3 * self.d_model * e.d_expert
+                n += ((e.num_experts + e.num_shared) * 3 * self.d_model
+                      * e.d_expert)
                 n += self.d_model * e.num_experts                # router
             else:
                 n += 3 * self.d_model * self.d_ff                # swiglu
@@ -191,7 +247,9 @@ class ModelConfig:
         """KV-cache bytes per token per sequence (the paper's core metric)."""
         b = 0.0
         for i in range(self.num_layers):
-            if self.layer_kind(i) == "attn":
+            if self.layer_kind(i) == "attn" and self.is_mla:
+                b += self.latent_dim * bytes_per_elt
+            elif self.layer_kind(i) == "attn":
                 b += 2 * self.num_kv_heads * self.head_dim * bytes_per_elt
         return b
 

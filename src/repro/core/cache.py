@@ -126,15 +126,25 @@ class LayerKV(NamedTuple):
 def init_layer_kv(
     spec: CacheSpec, batch: int, max_len: int, kv_heads: int, head_dim: int,
     dtype=jnp.bfloat16, *, as_spec: bool = False, logical_budget: int | None = None,
+    v_dim: Optional[int] = None,
 ) -> LayerKV:
-    """Zeros (or ShapeDtypeStructs when as_spec=True) for one layer."""
+    """Zeros (or ShapeDtypeStructs when as_spec=True) for one layer.
+
+    v_dim: width of the V store, default `head_dim`. A latent (MLA)
+    store (`kv_heads` 0) has no head axis and no V of its own: its rows
+    are k [B, S, head_dim] and v is [B, S, 0]; a row's value is its own
+    first r lanes (`is_latent`). Without a head axis of 1 the TPU lays
+    the rows out whole, and a kernel reads them in place."""
     S = spec.main_store_len(max_len)
     W = spec.window
     G = spec.group if spec.quantized else max(spec.group, 1)
     SG = S // G if spec.quantized else 0
     store_dt = jnp.int8 if spec.quantized else dtype
     B, H, D = batch, kv_heads, head_dim
+    Dv = D if v_dim is None else v_dim
     Dp = D * spec.bits // 8 if spec.quantized else D  # packed trailing dim
+    Dvp = Dv * spec.bits // 8 if spec.quantized else Dv
+    hd = (H,) if H else ()          # a latent store has no head axis
 
     def mk(shape, dt):
         if as_spec:
@@ -148,14 +158,14 @@ def init_layer_kv(
 
     lb = logical_budget if logical_budget is not None else S
     return LayerKV(
-        k=mk((B, S, H, Dp), store_dt),
-        v=mk((B, S, H, Dp), store_dt),
-        k_scale=mk((B, SG, H, D), jnp.float32),
-        k_zero=mk((B, SG, H, D), jnp.float32),
-        v_scale=mk((B, S if spec.quantized else 0, H), jnp.float32),
-        v_zero=mk((B, S if spec.quantized else 0, H), jnp.float32),
-        rk=mk((B, W, H, D), dtype),
-        rv=mk((B, W, H, D), dtype),
+        k=mk((B, S, *hd, Dp), store_dt),
+        v=mk((B, S, *hd, Dvp), store_dt),
+        k_scale=mk((B, SG, *hd, D), jnp.float32),
+        k_zero=mk((B, SG, *hd, D), jnp.float32),
+        v_scale=mk((B, S if spec.quantized else 0, *hd), jnp.float32),
+        v_zero=mk((B, S if spec.quantized else 0, *hd), jnp.float32),
+        rk=mk((B, W, *hd, D), dtype),
+        rv=mk((B, W, *hd, Dv), dtype),
         r_scores=mk((B, W), jnp.float32),
         scores=mk((B, S), jnp.float32),
         slot_pos=mkfull((B, S), jnp.int32, -1),
@@ -170,18 +180,18 @@ def init_layer_kv(
 def stacked_kv(
     spec: CacheSpec, n_layers: int, batch: int, max_len: int, kv_heads: int,
     head_dim: int, dtype=jnp.bfloat16, *, as_spec: bool = False,
-    layer_budgets: Optional[Array] = None,
+    layer_budgets: Optional[Array] = None, v_dim: Optional[int] = None,
 ) -> LayerKV:
     """Layer-stacked cache: every leaf gets a leading [n_layers] dim."""
     one = init_layer_kv(spec, batch, max_len, kv_heads, head_dim, dtype,
-                        as_spec=as_spec)
+                        as_spec=as_spec, v_dim=v_dim)
     if as_spec:
         stacked = jax.tree.map(
             lambda s: jax.ShapeDtypeStruct((n_layers, *s.shape), s.dtype), one
         )
     else:
         stacked = jax.tree.map(
-            lambda x: jnp.broadcast_to(x[None], (n_layers, *x.shape)).copy(), one
+            lambda x: jnp.broadcast_to(x[None], (n_layers, *x.shape)), one
         )
         if layer_budgets is not None:
             stacked = stacked._replace(budget=layer_budgets.astype(jnp.int32))
@@ -195,6 +205,11 @@ def stacked_kv(
 # ---------------------------------------------------------------------------
 # Views for attention: (K, V, additive mask) over main store + residual
 # ---------------------------------------------------------------------------
+
+
+def is_latent(lc) -> bool:
+    """A latent (MLA) store: rows in `k`, no V store of its own."""
+    return isinstance(lc, LayerKV) and lc.v.shape[-1] == 0
 
 
 def validity_bias(lc: LayerKV) -> Array:
@@ -725,10 +740,12 @@ def compress_prompt(
     key: Optional[Array] = None, dtype=jnp.bfloat16,
     logical_budget: Optional[Array] = None,
 ) -> LayerKV:
-    """k, v: [B, S_p, H, D] post-RoPE prompt KV; attn_mass: [B, S_p]
+    """k, v: [B, S_p, H, D] post-RoPE prompt KV (a latent store: k the
+    rows [B, S_p, D], v [B, S_p, 0]); attn_mass: [B, S_p]
     accumulated attention mass from the prefill pass. Returns a LayerKV at
     the physical budget (last `window` tokens -> residual ring, fp)."""
-    B, S_p, H, D = k.shape
+    B, S_p = k.shape[:2]
+    H, D = k.shape[2:] if k.ndim == 4 else (0, k.shape[2])
     S = spec.main_store_len(S_p)
     W = spec.window
     positions = jnp.broadcast_to(jnp.arange(S_p)[None], (B, S_p))
@@ -736,7 +753,7 @@ def compress_prompt(
     if S >= S_p and not spec.quantized and W == 0:
         # no selection needed: place the prompt verbatim (headroom allowed)
         lc = init_layer_kv(spec, B, S_p if spec.budget == 0 else S_p,
-                           H, D, dtype)
+                           H, D, dtype, v_dim=v.shape[-1])
         pad = S - S_p
         def padded(x, fill=0):
             return jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2),
@@ -795,7 +812,7 @@ def compress_prompt(
     valid = jnp.arange(S)[None] < n_valid               # [1|B, S]
     valid = jnp.broadcast_to(valid, (B, S)) if valid.shape[0] == 1 else valid
 
-    lc = init_layer_kv(spec, B, S_p, H, D, dtype)
+    lc = init_layer_kv(spec, B, S_p, H, D, dtype, v_dim=v.shape[-1])
     lc = lc._replace(budget=jnp.asarray(lb, jnp.int32).reshape(()))
     if spec.quantized:
         G = spec.group

@@ -121,15 +121,17 @@ def gqa_attention(
     q_positions: Optional[Array] = None, kv_positions: Optional[Array] = None,
     kv_bias: Optional[Array] = None, q_chunk: int = 512,
     return_mass: bool = False, mass_group: Optional[int] = None,
-    mass_init: Optional[Array] = None,
+    mass_init: Optional[Array] = None, scale: Optional[float] = None,
 ):
     """General GQA attention.
 
-    q: [B, Tq, Hq, D]; k, v: [B, Tk, Hkv, D].
+    q, k: [B, Tq|Tk, Hq|Hkv, D]; v: [B, Tk, Hkv, Dv] (Dv may differ
+    from D, as MLA's expanded heads do). scale: softmax scale, default
+    1/sqrt(D).
     kv_bias: [B, Tk] additive validity bias.
     Chunked over Tq (flash-style memory profile in pure XLA: scores are
     never materialized beyond [.., q_chunk, Tk]).
-    Returns out [B, Tq, Hq, D] (+ attention mass [B, Tk] if requested).
+    Returns out [B, Tq, Hq, Dv] (+ attention mass [B, Tk] if requested).
 
     mass_group / mass_init: canonical grouped mass accumulation (see
     `_fold_mass`). `mass_init` seeds the fold — chunked prefill passes
@@ -137,9 +139,10 @@ def gqa_attention(
     exact association chain of one monolithic call.
     """
     B, Tq, Hq, D = q.shape
-    Hkv = k.shape[2]
+    Hkv, Dv = k.shape[2], v.shape[3]
     G = Hq // Hkv
-    scale = 1.0 / math.sqrt(D)
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
     qg = q.reshape(B, Tq, Hkv, G, D)
     if q_positions is None:
         q_positions = jnp.broadcast_to(jnp.arange(Tq)[None], (B, Tq))
@@ -167,7 +170,7 @@ def gqa_attention(
              else jnp.zeros((B, k.shape[1]), jnp.float32))
     if Tq <= q_chunk:
         o, row_mass = _attend_block(qg, k, v, bias_for(q_positions), scale)
-        out = o.reshape(B, Tq, Hq, D)
+        out = o.reshape(B, Tq, Hq, Dv)
         if not return_mass:
             return out
         return out, _fold_mass(mass0, row_mass, mass_group)
@@ -182,7 +185,7 @@ def gqa_attention(
         pp = jnp.pad(q_positions, ((0, 0), (0, pad)), mode="edge")
         out = gqa_attention(qp, k, v, causal=causal, window=window,
                             q_positions=pp, kv_positions=kv_positions,
-                            kv_bias=kv_bias, q_chunk=q_chunk)
+                            kv_bias=kv_bias, q_chunk=q_chunk, scale=scale)
         return out[:, :Tq]
     n = Tq // q_chunk
     qg_c = qg.reshape(B, n, q_chunk, Hkv, G, D).transpose(1, 0, 2, 3, 4, 5)
@@ -194,7 +197,7 @@ def gqa_attention(
         return _fold_mass(carry_mass, row_mass, mass_group), o
 
     mass, outs = jax.lax.scan(body, mass0, (qg_c, qpos_c))
-    out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, Tq, Hq, D)
+    out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, Tq, Hq, Dv)
     return (out, mass) if return_mass else out
 
 

@@ -12,6 +12,7 @@ from repro.core import cache as kvcache
 from repro.core.cache import CacheSpec, LayerKV, SSMState
 from repro.nn import attention as attn
 from repro.nn import layers as L
+from repro.nn import mla
 from repro.nn import moe as moe_lib
 from repro.nn import ssm as ssm_lib
 
@@ -30,14 +31,16 @@ def block_init(key, cfg, kind: str, ffn_kind: str, *, cross: bool = False) -> di
     ks = jax.random.split(key, 8)
     p: dict = {"norm1": L.rmsnorm_init(cfg.d_model, cfg.dtype)}
     if kind == "attn":
-        p["attn"] = attn.attn_init(ks[0], cfg)
+        p["attn"] = (mla.mla_init(ks[0], cfg) if cfg.is_mla
+                     else attn.attn_init(ks[0], cfg))
     else:
         p["ssm"] = ssm_lib.ssm_init(ks[1], cfg)
     if cfg.d_ff > 0 or (ffn_kind == "moe"):
         p["norm2"] = L.rmsnorm_init(cfg.d_model, cfg.dtype)
         if ffn_kind == "moe":
             p["moe"] = moe_lib.moe_init(ks[2], cfg.d_model, cfg.moe.d_expert,
-                                        cfg.moe.num_experts, cfg.dtype)
+                                        cfg.moe.num_experts, cfg.dtype,
+                                        num_shared=cfg.moe.num_shared)
         else:
             p["mlp"] = L.mlp_init(ks[3], cfg.d_model, cfg.d_ff,
                                   bias=cfg.mlp_bias, dtype=cfg.dtype)
@@ -49,13 +52,31 @@ def block_init(key, cfg, kind: str, ffn_kind: str, *, cross: bool = False) -> di
 
 def _ffn(p: dict, x: Array, cfg) -> tuple[Array, BlockAux]:
     if "moe" in p:
+        e = cfg.moe
         y, aux = moe_lib.moe_apply(p["moe"], L.rmsnorm(p["norm2"], x, cfg.norm_eps),
-                                   top_k=cfg.moe.num_experts_per_tok,
-                                   capacity_factor=cfg.moe.capacity_factor)
+                                   top_k=e.num_experts_per_tok,
+                                   capacity_factor=e.capacity_factor,
+                                   norm_topk=e.norm_topk)
         return x + y, BlockAux(aux.load_balance_loss, aux.router_z_loss)
     if "mlp" in p:
         return x + L.mlp(p["mlp"], L.rmsnorm(p["norm2"], x, cfg.norm_eps)), ZERO_AUX
     return x, ZERO_AUX
+
+
+def _ffn_serve(p: dict, x: Array, cfg, live: Optional[Array] = None):
+    """The FFN of the serving paths: MoE layers route drop-free, so a
+    token's output depends on that token alone. `live` ([B, T] bool or
+    None) marks the rows the MoE counters count. Returns (x, this
+    call's `moe.MoEStats`, or None for a dense layer)."""
+    if "moe" not in p:
+        return _ffn(p, x, cfg)[0], None
+    e = cfg.moe
+    y, st = moe_lib.moe_apply_dropfree(
+        p["moe"], L.rmsnorm(p["norm2"], x, cfg.norm_eps),
+        top_k=e.num_experts_per_tok, norm_topk=e.norm_topk,
+        use_kernel=attn.resolve_use_kernels(getattr(cfg, "use_kernels",
+                                                    None)), live=live)
+    return x + y, st
 
 
 def _cross_attend(p: dict, x: Array, memory_kv, cfg) -> Array:
@@ -130,8 +151,22 @@ def block_prefill(p: dict, x: Array, cfg, kind: str, spec: CacheSpec, *,
                   positions: Optional[Array] = None,
                   logical_budget: Optional[Array] = None,
                   key: Optional[Array] = None, memory_kv=None):
-    """Returns (x, aux, LayerKV | SSMState)."""
+    """Returns (x, MoE stats or None, LayerKV | SSMState)."""
     h = L.rmsnorm(p["norm1"], x, cfg.norm_eps)
+    if kind == "attn" and cfg.is_mla:
+        # expanded form over the prompt's own latent rows; the store
+        # keeps the rows alone (see nn/mla.py)
+        pa = p["attn"]
+        rows = mla.latent_rows(pa, h, cfg, positions)
+        q_nope, q_pe = mla.queries(pa, h, cfg, positions)
+        o = mla.attend_expanded(pa, q_nope, q_pe, rows, cfg, positions)
+        x = x + L.linear(pa["wo"], o)
+        lc = kvcache.compress_prompt(
+            spec, rows, rows[..., :0],
+            jnp.zeros(x.shape[:2], jnp.float32), key=key, dtype=cfg.dtype,
+            logical_budget=logical_budget)
+        x, st = _ffn_serve(p, x, cfg)
+        return x, st, lc
     if kind == "attn":
         q, k, v = attn.qkv(p["attn"], h, cfg, positions)
         if _use_flash_prefill(cfg, True, positions) and not spec.track_scores():
@@ -153,14 +188,14 @@ def block_prefill(p: dict, x: Array, cfg, kind: str, spec: CacheSpec, *,
         lc = kvcache.compress_prompt(spec, k, v, mass, key=key, dtype=cfg.dtype,
                                      logical_budget=logical_budget)
         x = _cross_attend(p, x, memory_kv, cfg)
-        x, aux = _ffn(p, x, cfg)
-        return x, aux, lc
+        x, moe_st = _ffn_serve(p, x, cfg)
+        return x, moe_st, lc
     else:
         o, st = ssm_lib.mamba2_forward(p["ssm"], h, cfg)
         x = x + o
         x = _cross_attend(p, x, memory_kv, cfg)
-        x, aux = _ffn(p, x, cfg)
-        return x, aux, st
+        x, moe_st = _ffn_serve(p, x, cfg)
+        return x, moe_st, st
 
 
 # ---------------------------------------------------------------------------
@@ -172,7 +207,7 @@ def block_prefill_chunk(p: dict, x: Array, cfg, spec: CacheSpec,
                         k_scr: Array, v_scr: Array, mass_scr: Array,
                         positions: Array):
     """One attention layer's step of a chunked prefill (attn blocks only —
-    `nn.model.prefill_chunk` gates SSM/MoE archs).
+    `nn.model.prefill_chunk` gates SSM archs).
 
     x: [1, C, d_model] — the current segment's hidden states; positions:
     [1, C] absolute prompt positions (contiguous, MASS_GROUP-aligned
@@ -187,10 +222,26 @@ def block_prefill_chunk(p: dict, x: Array, cfg, spec: CacheSpec,
     `cache.compress_prompt` at finalize) are bit-identical to a
     monolithic `block_prefill` over the whole prompt.
 
-    Returns (x, k_scr, v_scr, mass_scr) updated."""
+    A latent (MLA) scratch holds the prompt's latent rows in k_scr [1, T,
+    latent_store_dim] (v_scr is zero-wide): the segment's rows are
+    written, and its queries attend over the whole scratch expanded to
+    per-head K/V (re-expanded every segment; only rows go into the
+    store).
+
+    Returns (x, k_scr, v_scr, mass_scr, MoE stats or None) updated."""
     h = L.rmsnorm(p["norm1"], x, cfg.norm_eps)
-    q, k, v = attn.qkv(p["attn"], h, cfg, positions)
     c0 = positions[0, 0]
+    if cfg.is_mla:
+        pa = p["attn"]
+        rows = mla.latent_rows(pa, h, cfg, positions)
+        k_scr = jax.lax.dynamic_update_slice_in_dim(
+            k_scr, rows.astype(k_scr.dtype), c0, axis=1)
+        q_nope, q_pe = mla.queries(pa, h, cfg, positions)
+        o = mla.attend_expanded(pa, q_nope, q_pe, k_scr, cfg, positions)
+        x = x + L.linear(pa["wo"], o)
+        x, st = _ffn_serve(p, x, cfg)
+        return x, k_scr, v_scr, mass_scr, st
+    q, k, v = attn.qkv(p["attn"], h, cfg, positions)
     k_scr = jax.lax.dynamic_update_slice_in_dim(k_scr, k.astype(k_scr.dtype),
                                                 c0, axis=1)
     v_scr = jax.lax.dynamic_update_slice_in_dim(v_scr, v.astype(v_scr.dtype),
@@ -209,8 +260,8 @@ def block_prefill_chunk(p: dict, x: Array, cfg, spec: CacheSpec,
             mass_group=attn.MASS_GROUP, mass_init=mass_scr)
     B, C, _ = x.shape
     x = x + L.linear(p["attn"]["wo"], o.reshape(B, C, -1))
-    x, _ = _ffn(p, x, cfg)
-    return x, k_scr, v_scr, mass_scr
+    x, st = _ffn_serve(p, x, cfg)
+    return x, k_scr, v_scr, mass_scr, st
 
 
 def _use_flash_prefill_chunk(cfg, spec: CacheSpec) -> bool:
@@ -253,7 +304,7 @@ def block_verify(p: dict, x: Array, cfg, spec: CacheSpec, lc,
         q, lc, spec, q_pos=positions, window=cfg.sliding_window,
         dtype=cfg.dtype, use_kernels=getattr(cfg, "use_kernels", None))
     x = x + L.linear(p["attn"]["wo"], o.reshape(B, Lseg, -1))
-    x, _ = _ffn(p, x, cfg)
+    x, _ = _ffn_serve(p, x, cfg)
     return x, lc, row_mass
 
 
@@ -265,8 +316,10 @@ def block_verify(p: dict, x: Array, cfg, spec: CacheSpec, lc,
 def block_decode(p: dict, x: Array, cfg, kind: str, spec: CacheSpec,
                  cache_piece, *, key: Optional[Array] = None, memory_kv=None,
                  append_mask: Optional[Array] = None,
-                 layer: Optional[Array] = None):
-    """x: [B, 1, d_model]. Returns (x, new cache piece).
+                 layer: Optional[Array] = None,
+                 live: Optional[Array] = None):
+    """x: [B, 1, d_model]. Returns (x, new cache piece, this call's MoE
+    stats or None for a dense layer).
 
     append_mask: optional [B] bool — rows where it is False leave the
     cache untouched (their attention output is still computed, and
@@ -275,9 +328,30 @@ def block_decode(p: dict, x: Array, cfg, kind: str, spec: CacheSpec,
 
     layer: optional int32 scalar — the piece's k/v are the layer-stacked
     dense stores, written and attended at layer `layer` in place
-    (`cache.append_token_dense`)."""
+    (`cache.append_token_dense`).
+
+    live: optional [B] bool — the slots serving a request, the only rows
+    the MoE counters count (`moe.moe_apply_dropfree`).
+
+    A latent (MLA) store appends the token's latent row and attends in
+    the absorbed form (`nn/mla.py`, `kernels/decode_mla`)."""
     h = L.rmsnorm(p["norm1"], x, cfg.norm_eps)
-    if kind == "attn":
+    if kind == "attn" and cfg.is_mla:
+        lc: LayerKV = cache_piece
+        pa = p["attn"]
+        pos = lc.pos[:, None]                                  # [B, 1]
+        rows = mla.latent_rows(pa, h, cfg, pos)[:, 0]          # [B, D]
+        lc = kvcache.append_token(lc, spec, rows, rows[:, :0], key=key,
+                                  mask=append_mask, layer=layer)
+        q_nope, q_pe = mla.queries(pa, h, cfg, pos)
+        q = mla.absorb_query(pa, q_nope, q_pe, cfg)[:, 0]       # [B, H, D]
+        o_lat = mla.decode_attention(
+            q, lc, cfg, layer=layer,
+            use_kernels=getattr(cfg, "use_kernels", None))
+        x = x + L.linear(pa["wo"], mla.absorbed_output(pa, o_lat[:, None],
+                                                       cfg))
+        new_piece = lc
+    elif kind == "attn":
         lc: LayerKV = cache_piece
         pos = lc.pos[:, None]                                  # [B, 1]
         q, k_new, v_new = attn.qkv(p["attn"], h, cfg, pos)
@@ -299,5 +373,6 @@ def block_decode(p: dict, x: Array, cfg, kind: str, spec: CacheSpec,
         x = x + o
         new_piece = st
     x = _cross_attend(p, x, memory_kv, cfg)
-    x, _ = _ffn(p, x, cfg)
-    return x, new_piece
+    x, moe_st = _ffn_serve(p, x, cfg,
+                           None if live is None else live[:, None])
+    return x, new_piece, moe_st

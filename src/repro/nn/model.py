@@ -8,9 +8,15 @@ body regardless of depth, which keeps 512-device dry-run compiles fast.
 Within a superblock, sublayer kinds (attn|ssm × dense|moe) are unrolled
 statically.
 
+Leading dense layers (DeepSeek's `first_k_dense_replace`) are held apart
+as `params["lead"]`, stacked the same way, and every layer loop scans
+them first (`_scan_layers`); caches stay stacked over all layers.
+
 The decode cache is a `ModelCache`: compressed `LayerKV` stacks for
-attention layers (the survey's subject), `SSMState` stacks for Mamba
-layers, and static cross-attention memory for enc-dec.
+attention layers (the survey's subject) — for latent attention (MLA)
+one latent row per token and layer (`cache.is_latent`) — `SSMState`
+stacks for Mamba layers, static cross-attention memory for enc-dec,
+and the drop-free MoE routing counters the serving loop publishes.
 """
 from __future__ import annotations
 
@@ -26,6 +32,7 @@ from repro.kernels.blocking import reads_in_place
 from repro.nn import blocks as B
 from repro.nn import layers as L
 from repro.nn import ssm as ssm_lib
+from repro.nn.moe import MoEStats, add_stats
 
 Array = jax.Array
 
@@ -36,6 +43,8 @@ class ModelCache(NamedTuple):
     cross_k: Any     # [L, B, Ts, Hkv, D] enc-dec only, else None
     cross_v: Any
     cross_bias: Any  # [B, Ts]
+    moe: Any = None  # MoEStats summed over the MoE layer calls of the
+                     # steps and prompts this cache went through (MoE only)
 
 
 class TrainAux(NamedTuple):
@@ -49,13 +58,35 @@ class TrainAux(NamedTuple):
 
 
 def sb_layout(cfg):
-    """Returns (sb, n_sb, kinds) where kinds[i] = (mixer_kind, ffn_kind)."""
+    """Returns (sb, n_sb, kinds) where kinds[i] = (mixer_kind, ffn_kind)
+    of the superblocks after the leading dense layers; n_sb counts the
+    leading layers too (each is a superblock of one)."""
     p1 = cfg.attn_layer_period if cfg.attn_layer_period > 0 else 1
     p2 = cfg.moe.layer_period if cfg.is_moe else 1
     sb = math.lcm(p1, p2)
+    lead = cfg.lead_layers
     assert cfg.num_layers % sb == 0, (cfg.num_layers, sb)
-    kinds = [(cfg.layer_kind(i), cfg.ffn_kind(i)) for i in range(sb)]
+    assert not lead or sb == 1, "leading dense layers need uniform layers"
+    kinds = [(cfg.layer_kind(lead + i), cfg.ffn_kind(lead + i))
+             for i in range(sb)]
     return sb, cfg.num_layers // sb, kinds
+
+
+def _scan_layers(cfg, params, body, carry, xs: tuple):
+    """`lax.scan` of `body(carry, (p_sb, *xs_i))` over the layer stack:
+    the leading dense layers (`params["lead"]`) first, then
+    `params["blocks"]`. Leaves of `xs` and of the stacked outputs span
+    all n_sb superblocks."""
+    n = cfg.lead_layers
+    if not n:
+        return jax.lax.scan(body, carry, (params["blocks"], *xs))
+    cut = lambda a, b: jax.tree.map(lambda t: t[a:b], xs)
+    carry, ys0 = jax.lax.scan(body, carry, (params["lead"], *cut(0, n)))
+    carry, ys1 = jax.lax.scan(body, carry,
+                              (params["blocks"], *cut(n, None)))
+    return carry, jax.tree.map(lambda a, b: jnp.concatenate([a, b]),
+                               ys0, ys1)
+
 
 
 def attn_positions(cfg):
@@ -91,7 +122,13 @@ def init_params(key: Array, cfg) -> dict:
             for i in range(sb)
         }
 
-    params["blocks"] = jax.vmap(init_sb)(jax.random.split(keys[1], n_sb))
+    lead = cfg.lead_layers
+    params["blocks"] = jax.vmap(init_sb)(jax.random.split(keys[1],
+                                                          n_sb - lead))
+    if lead:
+        params["lead"] = jax.vmap(
+            lambda k: {"sub0": B.block_init(k, cfg, "attn", "dense")})(
+                jax.random.split(keys[4], lead))
     if not cfg.tie_embeddings:
         params["head"] = L.linear_init(keys[2], cfg.d_model, cfg.vocab_size,
                                        bias=False, dtype=cfg.dtype)
@@ -157,8 +194,9 @@ def train_forward(params, cfg, batch: dict):
         memory = encode(params, cfg, batch["src_embeds"].astype(cfg.dtype))
     sb, n_sb, kinds = sb_layout(cfg)
 
-    def body(carry, p_sb):
+    def body(carry, xs):
         x, lb, zl = carry
+        p_sb, = xs
         for i in range(sb):
             mk = None
             if cfg.is_encoder_decoder:
@@ -171,9 +209,8 @@ def train_forward(params, cfg, batch: dict):
             lb, zl = lb + aux.lb_loss, zl + aux.z_loss
         return (x, lb, zl), None
 
-    (x, lb, zl), _ = jax.lax.scan(_maybe_remat(cfg, body),
-                                  (x, jnp.zeros(()), jnp.zeros(())),
-                                  params["blocks"])
+    (x, lb, zl), _ = _scan_layers(cfg, params, _maybe_remat(cfg, body),
+                                  (x, jnp.zeros(()), jnp.zeros(())), ())
     return _logits(params, cfg, x), TrainAux(lb, zl)
 
 
@@ -209,7 +246,8 @@ def prefill(params, cfg, batch: dict, spec: CacheSpec, *,
         layer_budgets = jnp.asarray(layer_budgets, jnp.int32).reshape(
             n_sb, max(len(aps), 1))
 
-    def body(x, xs):
+    def body(carry, xs):
+        x, stats = carry
         p_sb, ks, buds = xs
         attn_pieces, ssm_pieces = [], []
         for i in range(sb):
@@ -220,26 +258,28 @@ def prefill(params, cfg, batch: dict, spec: CacheSpec, *,
             if kinds[i][0] == "attn":
                 j = aps.index(i)
                 # positions=None: standard arange (flash-kernel eligible)
-                x, _, piece = B.block_prefill(
+                x, st, piece = B.block_prefill(
                     p_sb[f"sub{i}"], x, cfg, "attn", spec,
                     positions=None, logical_budget=buds[j],
                     key=ks[j], memory_kv=mkv)
                 attn_pieces.append(piece)
             else:
-                x, _, piece = B.block_prefill(
+                x, st, piece = B.block_prefill(
                     p_sb[f"sub{i}"], x, cfg, "ssm", spec,
                     positions=None, memory_kv=mkv)
                 ssm_pieces.append(piece)
+            stats = add_stats(stats, st)
         a = (jax.tree.map(lambda *xs: jnp.stack(xs), *attn_pieces)
              if attn_pieces else None)
         s = (jax.tree.map(lambda *xs: jnp.stack(xs), *ssm_pieces)
              if ssm_pieces else None)
-        return x, (a, s)
+        return (x, stats), (a, s)
 
-    x, (attn_c, ssm_c) = jax.lax.scan(body, x,
-                                      (params["blocks"], keys, layer_budgets))
+    stats = MoEStats.zero() if cfg.is_moe else None
+    (x, stats), (attn_c, ssm_c) = _scan_layers(
+        cfg, params, body, (x, stats), (keys, layer_budgets))
     logits = _logits(params, cfg, x[:, -1:])[:, 0]
-    return logits, ModelCache(attn_c, ssm_c, *cross)
+    return logits, ModelCache(attn_c, ssm_c, *cross, moe=stats)
 
 
 # ---------------------------------------------------------------------------
@@ -261,40 +301,56 @@ def prefill(params, cfg, batch: dict, spec: CacheSpec, *,
 # caches, logits, and greedy token streams (the serving contract;
 # tests/test_chunked_prefill.py).
 #
-# Attention-only decoder archs: SSM state and MoE capacity couple tokens
-# across segment boundaries, so those archs are gated (ValueError).
+# Attention-only decoder archs: SSM state couples tokens across segment
+# boundaries, so those archs are gated (ValueError). MoE layers route
+# drop-free on this path (`moe.moe_apply_dropfree`): a token's output
+# does not depend on the segment it shares.
 
 
 class PrefillState(NamedTuple):
     """Per-admission scratch: exact prompt K/V + running attention mass.
-    Leaves are layer-stacked like `ModelCache.attn` ([n_sb, nA, ...])."""
+    Leaves are layer-stacked like `ModelCache.attn` ([n_sb, nA, ...]).
+    A latent (MLA) scratch holds the latent rows in `k` ([.., 1, T,
+    latent_store_dim]) and a zero-wide `v`, as the latent store does."""
 
     k: Any      # [n_sb, nA, 1, T, Hkv, D] model dtype
     v: Any      # [n_sb, nA, 1, T, Hkv, D]
     mass: Any   # [n_sb, nA, 1, T] f32
+    moe: Any = None  # MoEStats of the prompt's segments (MoE only)
 
 
 def _check_chunkable(cfg) -> None:
+    """Chunked prefill runs decoder-only attention stacks (dense or
+    drop-free MoE FFNs); it refuses SSM layers and encoder-decoders."""
     if ssm_positions(cfg):
         raise ValueError("chunked prefill is attention-only: SSM state "
-                         "carries across segments (sequential scan)")
-    if cfg.is_moe:
-        raise ValueError("chunked prefill needs per-row MoE capacity: "
-                         "per-batch expert capacity couples segment "
-                         "tokens, so segmenting changes routing")
+                         "carries across segments (sequential scan), so "
+                         "SSM and hybrid models are refused")
     if cfg.is_encoder_decoder:
-        raise ValueError("chunked prefill is decoder-only")
+        raise ValueError("chunked prefill is decoder-only: "
+                         "encoder-decoder models are refused")
+
+
+def kv_store_dims(cfg) -> tuple[int, int, int]:
+    """(heads, K width, V width) of one cached row: an MLA model's latent
+    row (no head axis, `latent_store_dim` wide, no V of its own;
+    `init_layer_kv`), else (num_kv_heads, head_dim, head_dim)."""
+    if cfg.is_mla:
+        return 0, cfg.latent_store_dim, 0
+    return cfg.num_kv_heads, cfg.head_dim, cfg.head_dim
 
 
 def init_prefill_state(cfg, prompt_len: int) -> PrefillState:
     _check_chunkable(cfg)
     sb, n_sb, _ = sb_layout(cfg)
     nA = len(attn_positions(cfg))
-    H, D = cfg.num_kv_heads, cfg.head_dim
+    H, D, Dv = kv_store_dims(cfg)
+    hd = (H,) if H else ()
     return PrefillState(
-        k=jnp.zeros((n_sb, nA, 1, prompt_len, H, D), cfg.dtype),
-        v=jnp.zeros((n_sb, nA, 1, prompt_len, H, D), cfg.dtype),
+        k=jnp.zeros((n_sb, nA, 1, prompt_len, *hd, D), cfg.dtype),
+        v=jnp.zeros((n_sb, nA, 1, prompt_len, *hd, Dv), cfg.dtype),
         mass=jnp.zeros((n_sb, nA, 1, prompt_len), jnp.float32),
+        moe=MoEStats.zero() if cfg.is_moe else None,
     )
 
 
@@ -312,21 +368,23 @@ def prefill_chunk(params, cfg, st: PrefillState, tokens: Array, c0,
 
     assert all(k == "attn" for k, _ in kinds), "gated by _check_chunkable"
 
-    def body(x, xs):
+    def body(carry, xs):
+        x, stats = carry
         p_sb, k_sl, v_sl, m_sl = xs
         ks, vs, ms = [], [], []
         for i in range(sb):
             j = aps.index(i)
-            x, k_j, v_j, m_j = B.block_prefill_chunk(
+            x, k_j, v_j, m_j, moe_st = B.block_prefill_chunk(
                 p_sb[f"sub{i}"], x, cfg, spec,
                 k_sl[j], v_sl[j], m_sl[j], positions)
             ks.append(k_j); vs.append(v_j); ms.append(m_j)
-        return x, (jnp.stack(ks), jnp.stack(vs), jnp.stack(ms))
+            stats = add_stats(stats, moe_st)
+        return (x, stats), (jnp.stack(ks), jnp.stack(vs), jnp.stack(ms))
 
-    x, (k_n, v_n, m_n) = jax.lax.scan(
-        body, x, (params["blocks"], st.k, st.v, st.mass))
+    (x, stats), (k_n, v_n, m_n) = _scan_layers(
+        cfg, params, body, (x, st.moe), (st.k, st.v, st.mass))
     logits = _logits(params, cfg, x[:, -1:])[:, 0]
-    return logits, PrefillState(k_n, v_n, m_n)
+    return logits, PrefillState(k_n, v_n, m_n, stats)
 
 
 def prefill_finalize(cfg, st: PrefillState, spec: CacheSpec, *,
@@ -362,7 +420,7 @@ def prefill_finalize(cfg, st: PrefillState, spec: CacheSpec, *,
 
     _, attn_c = jax.lax.scan(
         body, 0, (st.k, st.v, st.mass, keys, layer_budgets))
-    return ModelCache(attn_c, None, None, None, None)
+    return ModelCache(attn_c, None, None, None, None, moe=st.moe)
 
 
 def prefill_finalize_meta(cfg, st: PrefillState, spec: CacheSpec, *,
@@ -445,19 +503,26 @@ def decode_in_place(cache: ModelCache, spec: CacheSpec) -> bool:
     then carried through the layer loop, each layer's new rows are
     scattered into the stack, and the decode kernel reads the layer where
     it lies — the stack is never sliced out, transposed or rebuilt.
-    Other stores pass through the loop layer by layer."""
+    A latent (MLA) store always is: its decode kernel reads whole rows
+    (`kernels/decode_mla`). Other stores pass through the loop layer by
+    layer."""
     return (isinstance(cache.attn, LayerKV) and not spec.quantized
-            and reads_in_place(*cache.attn.k.shape[-2:]))
+            and (kvcache.is_latent(cache.attn)
+                 or reads_in_place(*cache.attn.k.shape[-2:])))
 
 
 def decode_step(params, cfg, cache: ModelCache, token: Array,
                 spec: CacheSpec, *, key: Optional[Array] = None,
-                append_mask: Optional[Array] = None):
+                append_mask: Optional[Array] = None,
+                live: Optional[Array] = None):
     """token: [B, 1] int32. Returns (logits [B, V] f32, new ModelCache).
 
     append_mask: optional [B] bool — rows where it is False leave the
     cache untouched (ragged speculative drafting; attention-only archs,
     SSM state cannot be row-gated against its own step).
+
+    live: optional [B] bool — the slots serving a request; the MoE
+    counters (`ModelCache.moe`) count only their rows.
 
     A dense store is updated in place (`decode_in_place`): with the
     cache donated, the step's only writes to it are one row per slot and
@@ -478,13 +543,13 @@ def decode_step(params, cfg, cache: ModelCache, token: Array,
     attn_xs, kv = cache.attn, ()
     if in_place:
         # [n_sb, nA, B, S, H, D] -> [n_sb * nA, B, S, H, D]: a bitcast
-        kv_shape = cache.attn.k.shape
-        kv = tuple(t.reshape(-1, *kv_shape[2:])
+        kv_shapes = (cache.attn.k.shape, cache.attn.v.shape)
+        kv = tuple(t.reshape(t.shape[0] * t.shape[1], *t.shape[2:])
                    for t in (cache.attn.k, cache.attn.v))
         attn_xs = cache.attn._replace(k=None, v=None)
 
     def body(carry, xs):
-        x, kv = carry
+        x, kv, stats = carry
         p_sb, a_sl, s_sl, ks, ck, cv, i_sb = xs
         attn_pieces, ssm_pieces = [], []
         for i in range(sb):
@@ -498,11 +563,10 @@ def decode_step(params, cfg, cache: ModelCache, token: Array,
                 if in_place:
                     layer = i_sb * len(aps) + j
                     piece = piece._replace(k=kv[0], v=kv[1])
-                x, piece = B.block_decode(p_sb[f"sub{i}"], x, cfg, "attn",
-                                          spec, piece, key=ks[j],
-                                          memory_kv=mkv,
-                                          append_mask=append_mask,
-                                          layer=layer)
+                x, piece, st = B.block_decode(
+                    p_sb[f"sub{i}"], x, cfg, "attn", spec, piece,
+                    key=ks[j], memory_kv=mkv, append_mask=append_mask,
+                    layer=layer, live=live)
                 if in_place:
                     kv = (piece.k, piece.v)
                     piece = piece._replace(k=None, v=None)
@@ -510,26 +574,27 @@ def decode_step(params, cfg, cache: ModelCache, token: Array,
             else:
                 j = sps.index(i)
                 piece = jax.tree.map(lambda t: t[j], s_sl)
-                x, piece = B.block_decode(p_sb[f"sub{i}"], x, cfg, "ssm",
-                                          spec, piece, memory_kv=mkv)
+                x, piece, st = B.block_decode(
+                    p_sb[f"sub{i}"], x, cfg, "ssm", spec, piece,
+                    memory_kv=mkv, live=live)
                 ssm_pieces.append(piece)
+            stats = add_stats(stats, st)
         a = (jax.tree.map(lambda *xs: jnp.stack(xs), *attn_pieces)
              if attn_pieces else None)
         s = (jax.tree.map(lambda *xs: jnp.stack(xs), *ssm_pieces)
              if ssm_pieces else None)
-        return (x, kv), (a, s)
+        return (x, kv, stats), (a, s)
 
     cross_k = cache.cross_k if has_cross else jnp.zeros((n_sb, 0))
     cross_v = cache.cross_v if has_cross else jnp.zeros((n_sb, 0))
-    (x, kv), (attn_c, ssm_c) = jax.lax.scan(
-        body, (x, kv), (params["blocks"], attn_xs, cache.ssm, keys,
-                        cross_k, cross_v, jnp.arange(n_sb)))
+    (x, kv, stats), (attn_c, ssm_c) = _scan_layers(
+        cfg, params, body, (x, kv, cache.moe),
+        (attn_xs, cache.ssm, keys, cross_k, cross_v, jnp.arange(n_sb)))
     if in_place:
-        attn_c = attn_c._replace(k=kv[0].reshape(kv_shape),
-                                 v=kv[1].reshape(kv_shape))
+        attn_c = attn_c._replace(k=kv[0].reshape(kv_shapes[0]),
+                                 v=kv[1].reshape(kv_shapes[1]))
     logits = _logits(params, cfg, x)[:, 0]
-    return logits, ModelCache(attn_c, ssm_c, cache.cross_k, cache.cross_v,
-                              cache.cross_bias)
+    return logits, cache._replace(attn=attn_c, ssm=ssm_c, moe=stats)
 
 
 # ---------------------------------------------------------------------------
@@ -551,9 +616,9 @@ def decode_step(params, cfg, cache: ModelCache, token: Array,
 # order with exact-zero padding, so the score state (H2O et al.) is
 # bit-identical to the sequential decode it replaces.
 #
-# Attention-only decoder archs (same gate as chunked prefill): SSM
-# state cannot be rolled back row-wise, and per-batch MoE capacity
-# couples segment tokens.
+# Dense attention-only decoder archs (the chunked-prefill gate, and no
+# routed experts or latent store yet): SSM state cannot be rolled back
+# row-wise.
 
 
 def _check_speculable(cfg) -> None:
@@ -561,6 +626,14 @@ def _check_speculable(cfg) -> None:
         _check_chunkable(cfg)
     except ValueError as e:
         raise ValueError(f"speculative decoding: {e}") from None
+    if cfg.is_moe:
+        raise ValueError("speculative decoding with routed experts is not "
+                         "supported: the verify step and the drafter "
+                         "have no MoE path")
+    if cfg.is_mla:
+        raise ValueError("speculative decoding over a latent (MLA) store "
+                         "is not supported: the verify step has no "
+                         "latent path")
 
 
 def verify_step(params, cfg, cache: ModelCache, tokens: Array,
@@ -653,6 +726,9 @@ def init_cache(cfg, spec: CacheSpec, batch: int, max_len: int, *,
     sb, n_sb, kinds = sb_layout(cfg)
     aps, sps = attn_positions(cfg), ssm_positions(cfg)
     attn_c = ssm_c = None
+    if paged and cfg.is_mla:
+        raise ValueError("a paged latent (MLA) pool is not supported: "
+                         "serve latent attention from the dense store")
     if aps:
         if paged:
             # block-table substrate: per-layer pools + shared table
@@ -667,15 +743,16 @@ def init_cache(cfg, spec: CacheSpec, batch: int, max_len: int, *,
                 spec, len(aps), batch, max_len, cfg.num_kv_heads,
                 cfg.head_dim, n_blocks=nb, block_len=bl, dtype=cfg.dtype)
         else:
+            H, D, Dv = kv_store_dims(cfg)
             one = kvcache.stacked_kv(
-                spec, len(aps), batch, max_len, cfg.num_kv_heads,
-                cfg.head_dim, cfg.dtype, as_spec=as_spec)
+                spec, len(aps), batch, max_len, H, D, cfg.dtype,
+                as_spec=as_spec, v_dim=Dv)
         if as_spec:
             attn_c = jax.tree.map(
                 lambda s: jax.ShapeDtypeStruct((n_sb, *s.shape), s.dtype), one)
         else:
             attn_c = jax.tree.map(
-                lambda x: jnp.broadcast_to(x[None], (n_sb, *x.shape)).copy(),
+                lambda x: jnp.broadcast_to(x[None], (n_sb, *x.shape)),
                 one)
         if layer_budgets is not None:
             lb = jnp.asarray(layer_budgets, jnp.int32).reshape(n_sb, len(aps))
@@ -704,4 +781,8 @@ def init_cache(cfg, spec: CacheSpec, batch: int, max_len: int, *,
             ck = jnp.zeros(shape_k, cfg.dtype)
             cv = jnp.zeros(shape_k, cfg.dtype)
             cb = jnp.zeros((batch, src_len), jnp.float32)
-    return ModelCache(attn_c, ssm_c, ck, cv, cb)
+    moe = None
+    if cfg.is_moe:
+        moe = (jax.eval_shape(MoEStats.zero) if as_spec
+               else MoEStats.zero())
+    return ModelCache(attn_c, ssm_c, ck, cv, cb, moe)

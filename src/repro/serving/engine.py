@@ -56,6 +56,7 @@ from repro.core import paging as paging_lib
 from repro.core.cache import CacheSpec, cache_logical_bytes_per_layer
 from repro.core.policy import CompressionPolicy
 from repro.nn import model as M
+from repro.nn import moe as moe_lib
 from repro.nn.attention import MASS_GROUP
 from repro.obs import NULL_METRICS, NULL_TRACER, Phases
 from repro.serving import cacheblend as cacheblend_lib
@@ -110,6 +111,14 @@ class ContinuousGenerationResult:
     # decode steps that updated a dense store in place
     # (`model.decode_in_place`); 0 for quantized, paged and speculative
     inplace_steps: int = 0
+    # drop-free MoE routing over the run's prompt segments and the
+    # decode rows of slots serving a request (MoE models only; empty
+    # slots' rows are left out): assignments, experts_hit (experts with
+    # at least one assignment, summed over layer calls), calls (MoE layer
+    # calls) and max_expert_load (the busiest expert's assignments over
+    # the mean, averaged over calls)
+    moe: Optional[dict] = None
+    latent_store_bytes: int = 0   # MLA: bytes of the dense latent store
     pool_blocks: int = 0          # paged runs only: reserved pool size,
     pool_block_bytes: int = 0     # bytes one block pins across layers,
     pool_peak_blocks: int = 0     # high-water allocated blocks
@@ -217,6 +226,14 @@ class Engine:
         self.metrics = metrics if metrics is not None else NULL_METRICS
 
         spec = policy.spec
+        if cfg.is_mla and spec.compressed:
+            raise ValueError(
+                f"policy {policy.name!r} compresses the cache, and a latent "
+                f"(MLA) store supports no quantized or evicting policy: "
+                f"serve it with the full policy")
+        if cfg.is_mla and paged:
+            raise ValueError("a paged latent (MLA) pool is not supported: "
+                             "serve latent attention with paged=False")
         if not spec.compressed:
             # uncompressed baseline still needs decode headroom (sized for
             # the largest bucket so every bucket shares one cache shape)
@@ -354,8 +371,9 @@ class Engine:
 
         self._prefill = jax.jit(_prefill)
 
-        def _step(p, cache, tok, k):
-            logits, cache = M.decode_step(p, cfg, cache, tok, self.spec, key=k)
+        def _step(p, cache, tok, k, live=None):
+            logits, cache = M.decode_step(p, cfg, cache, tok, self.spec,
+                                          key=k, live=live)
             nxt = self.sampler(logits, k)
             return nxt, cache
         # donate the live cache through decode and slot surgery so XLA
@@ -363,9 +381,11 @@ class Engine:
         # admission (donation is unimplemented on cpu and only warns there)
         dn = jax.default_backend() != "cpu"
         self._decode = jax.jit(_step, donate_argnums=(1,) if dn else ())
+        self._live = None    # (slots, mask) of the last `_live_rows`
 
         # per-slot cache surgery (continuous batching): one compile each,
         # `slot` is a traced operand so every slot index reuses it
+        # an insert also adds the prompt's MoE counters to the running ones
         def _insert(cache: M.ModelCache, pc: M.ModelCache, slot):
             attn = (kvcache.insert_request(cache.attn, slot, pc.attn,
                                            batch_axis=2)
@@ -373,8 +393,8 @@ class Engine:
             ssm = (kvcache.insert_request_tree(cache.ssm, slot, pc.ssm,
                                               batch_axis=2)
                    if cache.ssm is not None else None)
-            return M.ModelCache(attn, ssm, cache.cross_k, cache.cross_v,
-                                cache.cross_bias)
+            return cache._replace(attn=attn, ssm=ssm,
+                                  moe=moe_lib.add_stats(cache.moe, pc.moe))
 
         def _insert_paged(cache: M.ModelCache, pc: M.ModelCache, slot, ids,
                           n_skip, *, pool_write: bool = True):
@@ -390,8 +410,8 @@ class Engine:
             ssm = (kvcache.insert_request_tree(cache.ssm, slot, pc.ssm,
                                               batch_axis=2)
                    if cache.ssm is not None else None)
-            return M.ModelCache(attn, ssm, cache.cross_k, cache.cross_v,
-                                cache.cross_bias)
+            return cache._replace(attn=attn, ssm=ssm,
+                                  moe=moe_lib.add_stats(cache.moe, pc.moe))
 
         def _reset(cache: M.ModelCache, slot):
             if self.paged:
@@ -403,8 +423,7 @@ class Engine:
                         if cache.attn is not None else None)
             ssm = (kvcache.reset_slot_tree(cache.ssm, slot, batch_axis=2)
                    if cache.ssm is not None else None)
-            return M.ModelCache(attn, ssm, cache.cross_k, cache.cross_v,
-                                cache.cross_bias)
+            return cache._replace(attn=attn, ssm=ssm)
 
         if self.paged:
             self._insert = jax.jit(_insert_paged,
@@ -433,10 +452,9 @@ class Engine:
             # slot's granted pool blocks as they are computed, and the
             # insert writes metadata only — no end-of-prefill bulk scatter
             def _write_rows(c, rows, ks, vs):
-                return M.ModelCache(
-                    paging_lib.write_prefill_rows(c.attn, rows, ks, vs,
-                                                  batch_axis=2),
-                    c.ssm, c.cross_k, c.cross_v, c.cross_bias)
+                return c._replace(
+                    attn=paging_lib.write_prefill_rows(c.attn, rows, ks, vs,
+                                                       batch_axis=2))
 
             def _insert_meta(cache, pc, slot, ids, n_skip):
                 return _insert_paged(cache, pc, slot, ids, n_skip,
@@ -457,10 +475,9 @@ class Engine:
             # blocks into the slot's fresh exclusive blocks (the table
             # rewrite itself reuses `_grow_tbl` at offset 0)
             def _copy_blocks(c, src, dst):
-                return M.ModelCache(
-                    paging_lib.copy_pool_blocks(c.attn, src, dst,
-                                                batch_axis=2),
-                    c.ssm, c.cross_k, c.cross_v, c.cross_bias)
+                return c._replace(
+                    attn=paging_lib.copy_pool_blocks(c.attn, src, dst,
+                                                     batch_axis=2))
 
             self._copy_blocks = jax.jit(_copy_blocks,
                                         donate_argnums=(0,) if dn else ())
@@ -476,20 +493,18 @@ class Engine:
                                                      batch_axis=2)
 
             def _scatter_blocks(c, ids, payload):
-                return M.ModelCache(
-                    paging_lib.scatter_pool_blocks(c.attn, ids, payload,
-                                                   batch_axis=2),
-                    c.ssm, c.cross_k, c.cross_v, c.cross_bias)
+                return c._replace(
+                    attn=paging_lib.scatter_pool_blocks(c.attn, ids, payload,
+                                                        batch_axis=2))
 
             def _gather_meta(c, slot):
                 return paging_lib.gather_slot_meta(c.attn, slot,
                                                    batch_axis=2)
 
             def _restore_meta(c, slot, payload):
-                return M.ModelCache(
-                    paging_lib.scatter_slot_meta(c.attn, slot, payload,
-                                                 batch_axis=2),
-                    c.ssm, c.cross_k, c.cross_v, c.cross_bias)
+                return c._replace(
+                    attn=paging_lib.scatter_slot_meta(c.attn, slot, payload,
+                                                      batch_axis=2))
 
             # kvlint: ok(jit-donate: spill gather is read-only — the live cache must survive until the host copy lands)
             self._gather_blocks = jax.jit(_gather_blocks)
@@ -505,16 +520,14 @@ class Engine:
             # device half of lazy growth/rollback: write freshly granted
             # ids into a slot's table row / unmap released entries
             def _grow_tbl(c, slot, j0, ids):
-                return M.ModelCache(
-                    paging_lib.write_block_table(c.attn, slot, j0, ids,
-                                                 batch_axis=2),
-                    c.ssm, c.cross_k, c.cross_v, c.cross_bias)
+                return c._replace(
+                    attn=paging_lib.write_block_table(c.attn, slot, j0, ids,
+                                                      batch_axis=2))
 
             def _clear_tbl(c, slot, j0):
-                return M.ModelCache(
-                    paging_lib.clear_block_table_from(c.attn, slot, j0,
-                                                      batch_axis=2),
-                    c.ssm, c.cross_k, c.cross_v, c.cross_bias)
+                return c._replace(
+                    attn=paging_lib.clear_block_table_from(c.attn, slot, j0,
+                                                           batch_axis=2))
 
             self._grow_tbl = jax.jit(_grow_tbl,
                                      donate_argnums=(0,) if dn else ())
@@ -567,20 +580,17 @@ class Engine:
                 _dstep, donate_argnums=(1,) if dn else ())
 
             def _insert_draft(dc, pc, slot):
-                return M.ModelCache(
-                    kvcache.insert_request(dc.attn, slot, pc.attn,
-                                           batch_axis=2),
-                    dc.ssm, dc.cross_k, dc.cross_v, dc.cross_bias)
+                return dc._replace(
+                    attn=kvcache.insert_request(dc.attn, slot, pc.attn,
+                                                batch_axis=2))
 
             def _reset_draft(dc, slot):
-                return M.ModelCache(
-                    kvcache.reset_slot(dc.attn, slot, batch_axis=2),
-                    dc.ssm, dc.cross_k, dc.cross_v, dc.cross_bias)
+                return dc._replace(
+                    attn=kvcache.reset_slot(dc.attn, slot, batch_axis=2))
 
             def _truncate_draft(dc, m):
-                return M.ModelCache(
-                    kvcache.truncate_rows(dc.attn, dspec, m),
-                    dc.ssm, dc.cross_k, dc.cross_v, dc.cross_bias)
+                return dc._replace(
+                    attn=kvcache.truncate_rows(dc.attn, dspec, m))
 
             self._insert_draft = jax.jit(_insert_draft,
                                          donate_argnums=(0,) if dn else ())
@@ -642,10 +652,9 @@ class Engine:
                 keep_groups=degrade_keep_groups, tracer=self.trace)
 
             def _degrade(c, slot, n):
-                return M.ModelCache(
-                    paging_lib.degrade_slot_groups(c.attn, self.spec, slot,
-                                                   n, batch_axis=2),
-                    c.ssm, c.cross_k, c.cross_v, c.cross_bias)
+                return c._replace(
+                    attn=paging_lib.degrade_slot_groups(
+                        c.attn, self.spec, slot, n, batch_axis=2))
 
             self._degrade_op = jax.jit(_degrade,
                                        donate_argnums=(0,) if dn else ())
@@ -768,12 +777,14 @@ class Engine:
     # ------------------------------------------------------------------
     def _logical_bytes_per_seq(self) -> float:
         """Per-sequence logical cache bytes under the layer budgets."""
-        return sum(
-            cache_logical_bytes_per_layer(
-                self.spec, self.prompt_len + self.max_new,
-                self.cfg.num_kv_heads, self.cfg.head_dim)
-            * (lb / max(self.spec.budget, 1))
-            for lb in self.layer_budgets)
+        n = self.prompt_len + self.max_new
+        if self.cfg.is_mla:       # one bf16 latent row a position
+            per = self.spec.main_store_len(n) * self.cfg.latent_dim * 2
+        else:
+            per = cache_logical_bytes_per_layer(
+                self.spec, n, self.cfg.num_kv_heads, self.cfg.head_dim)
+        return sum(per * (lb / max(self.spec.budget, 1))
+                   for lb in self.layer_budgets)
 
     # ------------------------------------------------------------------
     def generate(self, prompts: np.ndarray,
@@ -834,7 +845,9 @@ class Engine:
             pend_t = 0
             for t in range(1, self.max_new):
                 self.key, k2 = jax.random.split(self.key)
-                tok_dev, cache = self._decode(self.params, cache, tok, k2)
+                tok_dev, cache = self._decode(
+                    self.params, cache, tok, k2,
+                    self._live_rows(range(w1 - w0)))
                 tok = tok_dev[:, None]
                 if pend_tok is not None:
                     # kvlint: ok(host-sync: the pipelined fetch — step t-1's tokens land behind step t's dispatch)
@@ -919,6 +932,17 @@ class Engine:
                     cache.attn, self.spec))
             if self.tier_pressure is not None:
                 tier_stats["pressure"] = dict(self.tier_pressure.stats)
+        moe = None
+        if cache.moe is not None:
+            st = jax.device_get(cache.moe)      # once, at run end
+            moe = dict(assignments=int(st.assignments),
+                       experts_hit=int(st.experts_hit),
+                       calls=int(st.calls),
+                       max_expert_load=float(st.load_ratio)
+                       / max(int(st.calls), 1))
+        latent = (int(cache.attn.k.nbytes)
+                  if cache.attn is not None and kvcache.is_latent(cache.attn)
+                  else 0)
         res = ContinuousGenerationResult(
             results=results,
             prefill_seconds=prefill_s,
@@ -940,6 +964,8 @@ class Engine:
             loop_seconds=loop_s,
             fetch_seconds=fetch_s,
             inplace_steps=inplace_steps,
+            moe=moe,
+            latent_store_bytes=latent,
             spec=spec_stats,
             prefix=prefix_stats,
             tier=tier_stats,
@@ -947,6 +973,20 @@ class Engine:
         )
         self._publish_metrics(sched, res)
         return res
+
+    def _live_rows(self, slots):
+        """[slots] bool on the device, True at the slots a decode step
+        serves, for the MoE counters to count those rows alone; kept
+        until the set changes. None for a dense model (nothing reads
+        it, so its decode program takes no such operand)."""
+        if not self.cfg.is_moe:
+            return None
+        got = tuple(sorted(slots))
+        if self._live is None or self._live[0] != got:
+            m = np.zeros(self.slots, bool)
+            m[list(got)] = True
+            self._live = (got, jnp.asarray(m))
+        return self._live[1]
 
     def _publish_metrics(self, sched, res) -> None:
         """End-of-run aggregates into the metrics registry (no-op under
@@ -969,6 +1009,13 @@ class Engine:
         mx.counter("engine.decode_tokens").inc(res.decode_tokens)
         mx.counter("engine.loop_iters").inc(res.loop_iters)
         mx.counter("decode.inplace_steps").inc(res.inplace_steps)
+        if res.moe is not None:
+            mx.counter("moe.assignments").inc(res.moe["assignments"])
+            mx.counter("moe.experts_hit").inc(res.moe["experts_hit"])
+            mx.counter("moe.calls").inc(res.moe["calls"])
+            mx.gauge("moe.max_expert_load").set(res.moe["max_expert_load"])
+        if res.latent_store_bytes:
+            mx.gauge("mla.latent_store_bytes").set(res.latent_store_bytes)
         mx.counter("slots.prefill_steps").inc(sched.prefill_slot_steps)
         mx.counter("slots.starved_steps").inc(sched.starved_slot_steps)
         mx.counter("slots.drain_steps").inc(sched.drain_slot_steps)
@@ -1337,9 +1384,10 @@ class Engine:
         immediately and its slot is handed to the next queued request.
         Bare arrays are wrapped as `Request(tokens, max_new=self.max_new)`.
 
-        Decoder-only archs (the survey's subject). MoE routing uses
-        per-batch expert capacity, so co-resident garbage slots could
-        perturb active rows there — dense/SSM archs are exact.
+        Decoder-only archs (the survey's subject). MoE layers route
+        drop-free here (`moe.moe_apply_dropfree`): a token's output
+        depends on that token alone, so co-resident slots never perturb
+        each other.
         """
         if self.cfg.is_encoder_decoder:
             raise NotImplementedError(
@@ -2142,7 +2190,8 @@ class Engine:
             if active:
                 self.key, k2 = jax.random.split(self.key)
                 tok_dev, cache = self._decode(self.params, cache,
-                                              tok_in[:, None], k2)
+                                              tok_in[:, None], k2,
+                                              self._live_rows(active))
                 sched.note_decode_step()
                 step_idx += 1
                 new_pending = (tok_dev, list(active))

@@ -89,8 +89,8 @@ def shared_decode_step(params, cfg, caches, token: Array, spec: CacheSpec,
             x = x + L.linear(p["attn"]["wo"], o.reshape(Bsz, 1, -1))
             x, _ = B._ffn(p, x, cfg)
         else:
-            x, new_caches[i] = B.block_decode(p, x, cfg, "attn", spec,
-                                              new_caches[i])
+            x, new_caches[i], _ = B.block_decode(p, x, cfg, "attn", spec,
+                                                 new_caches[i])
     logits = _final_logits(params, cfg, x)
     return logits, new_caches
 

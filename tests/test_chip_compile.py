@@ -217,3 +217,97 @@ def test_dense_decode_step_updates_store_in_place(one_chip, no_compile_cache,
         params, cache, tok, key).compile().as_text()
     assert "tpu_custom_call" in text
     assert _store_copies(text) == []
+
+
+# DeepSeek-V2-Lite's latent store in its cell: 32 slots, 14208 rows of
+# 512 + 64 values padded to 640 lanes
+_LATENT = re.compile(r"bf16\[(\d+,)*32,14208,640\]")
+
+
+def _latent_cfg():
+    from repro.configs.base import MoEConfig, ModelConfig, YarnScaling
+    return ModelConfig(
+        name="deepseek-v2-lite-2l", arch_type="moe", source="test",
+        num_layers=2, d_model=2048, num_heads=16, num_kv_heads=16,
+        d_ff=10944, vocab_size=102400, norm_eps=1e-6, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        rope_yarn=YarnScaling(factor=40, original_max_position=4096,
+                              mscale=0.707, mscale_all_dim=0.707),
+        moe=MoEConfig(num_experts=64, num_experts_per_tok=6, d_expert=1408,
+                      num_shared=2, norm_topk=False, first_dense=1),
+        use_kernels=True, remat="none")
+
+
+def test_latent_decode_step_updates_store_in_place(one_chip,
+                                                    no_compile_cache,
+                                                    monkeypatch):
+    """The decode step at DeepSeek-V2-Lite's widths (MLA, the dense layer
+    and an expert layer, 32 slots, 14208 rows), cache donated: the latent
+    decode kernel and the grouped expert matmul are there under their
+    trace names, and no op copies or restacks the latent store."""
+    from repro.kernels.decode_mla import ops as mla_ops
+    from repro.kernels.moe_gmm import ops as gmm_ops
+    monkeypatch.setattr(mla_ops, "resolve_interpret", lambda flag: False)
+    monkeypatch.setattr(gmm_ops, "resolve_interpret", lambda flag: False)
+    cfg = _latent_cfg()
+    spec = dataclasses.replace(FULL, budget=14208)
+    on_chip = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        tree)
+    params = on_chip(jax.eval_shape(
+        lambda: M.init_params(jax.random.key(0), cfg)))
+    cache = on_chip(jax.eval_shape(lambda: M.init_cache(cfg, spec, 32,
+                                                        14208)))
+    assert cache.attn.k.shape == (2, 1, 32, 14208, 640)
+    tok = jax.ShapeDtypeStruct((32, 1), jnp.int32, sharding=one_chip)
+    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+    # the engine's live-slot mask, which only the MoE counters read
+    live = jax.ShapeDtypeStruct((32,), jnp.bool_, sharding=one_chip)
+
+    def step(p, c, t, k, live):
+        logits, c = M.decode_step(p, cfg, c, t, spec, key=k, live=live)
+        return jnp.argmax(logits, -1), c
+
+    text = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, tok, key, live).compile().as_text()
+    assert re.search(r"%decode_mla_pallas(\.\d+)? = ", text)
+    assert re.search(r"%moe_gmm(\.\d+)? = ", text)
+    bad = []
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if m and _LATENT.search(m.group(2)) and (
+                m.group(3) in ("copy", "copy-start", "copy-done",
+                               "dynamic-update-slice")
+                or (m.group(3) == "custom-call" and "AllocateBuffer" in line)
+                or "copy" in m.group(1)):
+            bad.append(line[:160])
+    assert bad == []
+
+
+def test_latent_prompt_chunk_compiles_for_v5e(one_chip, no_compile_cache,
+                                              monkeypatch):
+    """A 512-token prompt chunk at DeepSeek-V2-Lite's widths against a
+    13,696-row latent scratch compiles for the chip: the grouped expert
+    matmul at 3072 assignment rows (a decode step has 192), and the
+    chunk's temporaries (the whole scratch expanded to per-head K/V and
+    attended, masked) under 2 GiB."""
+    from repro.kernels.moe_gmm import ops as gmm_ops
+    monkeypatch.setattr(gmm_ops, "resolve_interpret", lambda flag: False)
+    cfg = _latent_cfg()
+    spec = dataclasses.replace(FULL, budget=14208)
+    on_chip = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        tree)
+    params = on_chip(jax.eval_shape(
+        lambda: M.init_params(jax.random.key(0), cfg)))
+    st = on_chip(jax.eval_shape(lambda: M.init_prefill_state(cfg, 13696)))
+    toks = jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=one_chip)
+    c0 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+
+    def chunk(p, s, t, c):
+        return M.prefill_chunk(p, cfg, s, t, c, spec)
+
+    compiled = jax.jit(chunk, donate_argnums=(1,)).lower(
+        params, st, toks, c0).compile()
+    assert re.search(r"%moe_gmm(\.\d+)? = ", compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30

@@ -248,8 +248,9 @@ def _scan_decode_step(params, cfg, cache, token, spec, key, append_mask):
     def body(x, xs):
         p_sb, a_sl, ks = xs
         piece = jax.tree.map(lambda t: t[0], a_sl)
-        x, piece = Bk.block_decode(p_sb["sub0"], x, cfg, "attn", spec,
-                                   piece, key=ks[0], append_mask=append_mask)
+        x, piece, _ = Bk.block_decode(p_sb["sub0"], x, cfg, "attn", spec,
+                                      piece, key=ks[0],
+                                      append_mask=append_mask)
         return x, jax.tree.map(lambda t: t[None], piece)
 
     x, attn_c = jax.lax.scan(body, x, (params["blocks"], cache.attn, keys))

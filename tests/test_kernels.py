@@ -7,6 +7,7 @@ import pytest
 
 from repro.kernels.kvquant import kernel as kq_kernel, ref as kq_ref
 from repro.kernels.decode_qattn import kernel as dq_kernel, ref as dq_ref
+from repro.kernels.decode_mla import kernel as mla_kernel, ref as mla_ref
 from repro.kernels.flash_prefill import kernel as fp_kernel, ref as fp_ref
 
 
@@ -310,3 +311,52 @@ def test_flash_prefill_chunk_matches_square_kernel(window):
         np.testing.assert_allclose(np.asarray(o_ch),
                                    np.asarray(o_sq[:, c0:c1]), atol=1e-5,
                                    err_msg=f"chunk@{c0}")
+
+
+# ---------------------------------------------------------------------------
+# decode_mla: MQA over latent rows (kernels/decode_mla)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("stacked", [False, True])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("S,bs", [(200, 64), (256, 128), (40, 512)])
+def test_decode_mla_matches_ref(stacked, dtype, S, bs):
+    """Ragged valid lengths (one row, a block boundary, the whole store,
+    a partial last block that overhangs the store), every head reading
+    the same rows, values = the rows' first r lanes; a layer stack read
+    at its layer. bf16 inputs: the kernel's probabilities go to the MXU
+    in bf16, the oracle's stay f32 — 2e-2 on outputs of O(1)."""
+    B, H, D, r, L = 4, 16, 256, 128, 3
+    ks = jax.random.split(jax.random.key(0), 2)
+    q = jax.random.normal(ks[0], (B, H, D)).astype(dtype)
+    st = jax.random.normal(ks[1], (L, B, S, D)).astype(dtype)
+    nv = jnp.array([1, min(S, 64), S, max(S - 3, 1)], jnp.int32)
+    layer = 2
+    store = st if stacked else st[layer]
+    out = mla_kernel.decode_mla_pallas(
+        q, store, nv, jnp.int32(layer) if stacked else None, kv_lora=r,
+        scale=0.07, block_s=bs, interpret=True)
+    want = mla_ref.decode_mla_ref(q, st[layer], nv, kv_lora=r, scale=0.07)
+    assert out.shape == (B, H, r) and out.dtype == jnp.float32
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=tol,
+                               rtol=0)
+
+
+def test_decode_mla_reads_only_valid_rows_of_its_layer():
+    """Rows past a slot's length and other layers hold NaN: the output
+    stays finite and equal to the oracle over the valid rows."""
+    B, H, D, r, S = 2, 4, 128, 64, 96
+    q = jax.random.normal(jax.random.key(1), (B, H, D))
+    good = jax.random.normal(jax.random.key(2), (B, S, D))
+    nv = jnp.array([37, 96], jnp.int32)
+    st = jnp.full((3, B, S, D), jnp.nan).at[1].set(
+        jnp.where(jnp.arange(S)[None, :, None] < nv[:, None, None], good,
+                  jnp.nan))
+    out = mla_kernel.decode_mla_pallas(q, st, nv, jnp.int32(1), kv_lora=r,
+                                       scale=0.1, block_s=32, interpret=True)
+    want = mla_ref.decode_mla_ref(q, good, nv, kv_lora=r, scale=0.1)
+    assert bool(jnp.all(jnp.isfinite(out)))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5,
+                               rtol=0)
