@@ -63,11 +63,14 @@ def _ffn(p: dict, x: Array, cfg) -> tuple[Array, BlockAux]:
     return x, ZERO_AUX
 
 
-def _ffn_serve(p: dict, x: Array, cfg, live: Optional[Array] = None):
+def _ffn_serve(p: dict, x: Array, cfg, live: Optional[Array] = None,
+               block: Optional[Array] = None):
     """The FFN of the serving paths: MoE layers route drop-free, so a
     token's output depends on that token alone. `live` ([B, T] bool or
-    None) marks the rows the MoE counters count. Returns (x, this
-    call's `moe.MoEStats`, or None for a dense layer)."""
+    None) marks the rows the MoE counters count. `block` (int32 scalar
+    or None): `p["moe"]` holds the layer-stacked expert weights and this
+    layer is block `block` of them (`moe.moe_apply_dropfree`). Returns
+    (x, this call's `moe.MoEStats`, or None for a dense layer)."""
     if "moe" not in p:
         return _ffn(p, x, cfg)[0], None
     e = cfg.moe
@@ -75,7 +78,8 @@ def _ffn_serve(p: dict, x: Array, cfg, live: Optional[Array] = None):
         p["moe"], L.rmsnorm(p["norm2"], x, cfg.norm_eps),
         top_k=e.num_experts_per_tok, norm_topk=e.norm_topk,
         use_kernel=attn.resolve_use_kernels(getattr(cfg, "use_kernels",
-                                                    None)), live=live)
+                                                    None)), live=live,
+        block=block)
     return x + y, st
 
 
@@ -150,8 +154,11 @@ def block_train(p: dict, x: Array, cfg, kind: str, *,
 def block_prefill(p: dict, x: Array, cfg, kind: str, spec: CacheSpec, *,
                   positions: Optional[Array] = None,
                   logical_budget: Optional[Array] = None,
-                  key: Optional[Array] = None, memory_kv=None):
-    """Returns (x, MoE stats or None, LayerKV | SSMState)."""
+                  key: Optional[Array] = None, memory_kv=None,
+                  moe_block: Optional[Array] = None):
+    """Returns (x, MoE stats or None, LayerKV | SSMState). moe_block:
+    `p["moe"]` holds the layer-stacked expert weights, read at this
+    block (`_ffn_serve`)."""
     h = L.rmsnorm(p["norm1"], x, cfg.norm_eps)
     if kind == "attn" and cfg.is_mla:
         # expanded form over the prompt's own latent rows; the store
@@ -165,7 +172,7 @@ def block_prefill(p: dict, x: Array, cfg, kind: str, spec: CacheSpec, *,
             spec, rows, rows[..., :0],
             jnp.zeros(x.shape[:2], jnp.float32), key=key, dtype=cfg.dtype,
             logical_budget=logical_budget)
-        x, st = _ffn_serve(p, x, cfg)
+        x, st = _ffn_serve(p, x, cfg, block=moe_block)
         return x, st, lc
     if kind == "attn":
         q, k, v = attn.qkv(p["attn"], h, cfg, positions)
@@ -188,13 +195,13 @@ def block_prefill(p: dict, x: Array, cfg, kind: str, spec: CacheSpec, *,
         lc = kvcache.compress_prompt(spec, k, v, mass, key=key, dtype=cfg.dtype,
                                      logical_budget=logical_budget)
         x = _cross_attend(p, x, memory_kv, cfg)
-        x, moe_st = _ffn_serve(p, x, cfg)
+        x, moe_st = _ffn_serve(p, x, cfg, block=moe_block)
         return x, moe_st, lc
     else:
         o, st = ssm_lib.mamba2_forward(p["ssm"], h, cfg)
         x = x + o
         x = _cross_attend(p, x, memory_kv, cfg)
-        x, moe_st = _ffn_serve(p, x, cfg)
+        x, moe_st = _ffn_serve(p, x, cfg, block=moe_block)
         return x, moe_st, st
 
 
@@ -205,7 +212,7 @@ def block_prefill(p: dict, x: Array, cfg, kind: str, spec: CacheSpec, *,
 
 def block_prefill_chunk(p: dict, x: Array, cfg, spec: CacheSpec,
                         k_scr: Array, v_scr: Array, mass_scr: Array,
-                        positions: Array):
+                        positions: Array, moe_block: Optional[Array] = None):
     """One attention layer's step of a chunked prefill (attn blocks only —
     `nn.model.prefill_chunk` gates SSM archs).
 
@@ -228,6 +235,8 @@ def block_prefill_chunk(p: dict, x: Array, cfg, spec: CacheSpec,
     per-head K/V (re-expanded every segment; only rows go into the
     store).
 
+    moe_block: as `block_prefill`'s.
+
     Returns (x, k_scr, v_scr, mass_scr, MoE stats or None) updated."""
     h = L.rmsnorm(p["norm1"], x, cfg.norm_eps)
     c0 = positions[0, 0]
@@ -239,7 +248,7 @@ def block_prefill_chunk(p: dict, x: Array, cfg, spec: CacheSpec,
         q_nope, q_pe = mla.queries(pa, h, cfg, positions)
         o = mla.attend_expanded(pa, q_nope, q_pe, k_scr, cfg, positions)
         x = x + L.linear(pa["wo"], o)
-        x, st = _ffn_serve(p, x, cfg)
+        x, st = _ffn_serve(p, x, cfg, block=moe_block)
         return x, k_scr, v_scr, mass_scr, st
     q, k, v = attn.qkv(p["attn"], h, cfg, positions)
     k_scr = jax.lax.dynamic_update_slice_in_dim(k_scr, k.astype(k_scr.dtype),
@@ -260,7 +269,7 @@ def block_prefill_chunk(p: dict, x: Array, cfg, spec: CacheSpec,
             mass_group=attn.MASS_GROUP, mass_init=mass_scr)
     B, C, _ = x.shape
     x = x + L.linear(p["attn"]["wo"], o.reshape(B, C, -1))
-    x, st = _ffn_serve(p, x, cfg)
+    x, st = _ffn_serve(p, x, cfg, block=moe_block)
     return x, k_scr, v_scr, mass_scr, st
 
 
@@ -317,7 +326,8 @@ def block_decode(p: dict, x: Array, cfg, kind: str, spec: CacheSpec,
                  cache_piece, *, key: Optional[Array] = None, memory_kv=None,
                  append_mask: Optional[Array] = None,
                  layer: Optional[Array] = None,
-                 live: Optional[Array] = None):
+                 live: Optional[Array] = None,
+                 moe_block: Optional[Array] = None):
     """x: [B, 1, d_model]. Returns (x, new cache piece, this call's MoE
     stats or None for a dense layer).
 
@@ -332,6 +342,8 @@ def block_decode(p: dict, x: Array, cfg, kind: str, spec: CacheSpec,
 
     live: optional [B] bool — the slots serving a request, the only rows
     the MoE counters count (`moe.moe_apply_dropfree`).
+
+    moe_block: as `block_prefill`'s.
 
     A latent (MLA) store appends the token's latent row and attends in
     the absorbed form (`nn/mla.py`, `kernels/decode_mla`)."""
@@ -374,5 +386,6 @@ def block_decode(p: dict, x: Array, cfg, kind: str, spec: CacheSpec,
         new_piece = st
     x = _cross_attend(p, x, memory_kv, cfg)
     x, moe_st = _ffn_serve(p, x, cfg,
-                           None if live is None else live[:, None])
+                           None if live is None else live[:, None],
+                           block=moe_block)
     return x, new_piece, moe_st

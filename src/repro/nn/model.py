@@ -88,6 +88,49 @@ def _scan_layers(cfg, params, body, carry, xs: tuple):
                                ys0, ys1)
 
 
+_EXPERT_LEAVES = ("gate", "up", "down")
+
+
+def _experts_out(cfg, params):
+    """The serving loops' routed experts, held out of the layer scan.
+
+    Scanned, each MoE layer's `[E, k, n]` expert weights would be a slice
+    of their `[n_blocks, E, k, n]` stack, which the grouped matmul kernel
+    cannot read where it lies: the slice is copied first (three of them a
+    layer call). Instead each MoE sub-block's gate, up and down stacks are
+    flattened to `[n_blocks * E, k, n]` (a bitcast) and handed whole to
+    every block, with its index (`moe.moe_apply_dropfree`'s `block`).
+
+    Returns (params without those leaves, for `_scan_layers`; the stacks
+    by sub-block name; each scanned layer's block index, int32 [n_sb],
+    negative for the leading dense layers) — or (params, {}, None) where
+    no sub-block has routed experts."""
+    blocks = params["blocks"]
+    stacks = {name: {w: blk["moe"][w].reshape(-1, *blk["moe"][w].shape[2:])
+                     for w in _EXPERT_LEAVES}
+              for name, blk in blocks.items() if "moe" in blk}
+    if not stacks:
+        return params, stacks, None
+    blocks = {name: ({**blk, "moe": {k: v for k, v in blk["moe"].items()
+                                     if k not in _EXPERT_LEAVES}}
+                     if name in stacks else blk)
+              for name, blk in blocks.items()}
+    _, n_sb, _ = sb_layout(cfg)
+    return ({**params, "blocks": blocks}, stacks,
+            jnp.arange(n_sb, dtype=jnp.int32) - cfg.lead_layers)
+
+
+def _sub(p_sb, stacks, i, blk):
+    """Sub-block i's params inside a serving loop's scan body, and the
+    block index its FFN reads the expert stacks at (None where its
+    experts were not held out, `_experts_out`, and in the leading dense
+    layers)."""
+    p = p_sb[f"sub{i}"]
+    if "moe" not in p or f"sub{i}" not in stacks:
+        return p, None
+    return {**p, "moe": {**p["moe"], **stacks[f"sub{i}"]}}, blk
+
+
 
 def attn_positions(cfg):
     sb, n_sb, kinds = sb_layout(cfg)
@@ -246,27 +289,30 @@ def prefill(params, cfg, batch: dict, spec: CacheSpec, *,
         layer_budgets = jnp.asarray(layer_budgets, jnp.int32).reshape(
             n_sb, max(len(aps), 1))
 
+    p_scan, experts, blks = _experts_out(cfg, params)
+
     def body(carry, xs):
         x, stats = carry
-        p_sb, ks, buds = xs
+        p_sb, ks, buds, blk = xs
         attn_pieces, ssm_pieces = [], []
         for i in range(sb):
+            p_i, moe_block = _sub(p_sb, experts, i, blk)
             mkv = None
             if cfg.is_encoder_decoder:
-                k_, v_ = B.cross_kv(p_sb[f"sub{i}"], memory, cfg)
+                k_, v_ = B.cross_kv(p_i, memory, cfg)
                 mkv = (k_, v_, None)
             if kinds[i][0] == "attn":
                 j = aps.index(i)
                 # positions=None: standard arange (flash-kernel eligible)
                 x, st, piece = B.block_prefill(
-                    p_sb[f"sub{i}"], x, cfg, "attn", spec,
+                    p_i, x, cfg, "attn", spec,
                     positions=None, logical_budget=buds[j],
-                    key=ks[j], memory_kv=mkv)
+                    key=ks[j], memory_kv=mkv, moe_block=moe_block)
                 attn_pieces.append(piece)
             else:
                 x, st, piece = B.block_prefill(
-                    p_sb[f"sub{i}"], x, cfg, "ssm", spec,
-                    positions=None, memory_kv=mkv)
+                    p_i, x, cfg, "ssm", spec,
+                    positions=None, memory_kv=mkv, moe_block=moe_block)
                 ssm_pieces.append(piece)
             stats = add_stats(stats, st)
         a = (jax.tree.map(lambda *xs: jnp.stack(xs), *attn_pieces)
@@ -277,7 +323,7 @@ def prefill(params, cfg, batch: dict, spec: CacheSpec, *,
 
     stats = MoEStats.zero() if cfg.is_moe else None
     (x, stats), (attn_c, ssm_c) = _scan_layers(
-        cfg, params, body, (x, stats), (keys, layer_budgets))
+        cfg, p_scan, body, (x, stats), (keys, layer_budgets, blks))
     logits = _logits(params, cfg, x[:, -1:])[:, 0]
     return logits, ModelCache(attn_c, ssm_c, *cross, moe=stats)
 
@@ -368,21 +414,24 @@ def prefill_chunk(params, cfg, st: PrefillState, tokens: Array, c0,
 
     assert all(k == "attn" for k, _ in kinds), "gated by _check_chunkable"
 
+    p_scan, experts, blks = _experts_out(cfg, params)
+
     def body(carry, xs):
         x, stats = carry
-        p_sb, k_sl, v_sl, m_sl = xs
+        p_sb, k_sl, v_sl, m_sl, blk = xs
         ks, vs, ms = [], [], []
         for i in range(sb):
             j = aps.index(i)
+            p_i, moe_block = _sub(p_sb, experts, i, blk)
             x, k_j, v_j, m_j, moe_st = B.block_prefill_chunk(
-                p_sb[f"sub{i}"], x, cfg, spec,
-                k_sl[j], v_sl[j], m_sl[j], positions)
+                p_i, x, cfg, spec, k_sl[j], v_sl[j], m_sl[j], positions,
+                moe_block=moe_block)
             ks.append(k_j); vs.append(v_j); ms.append(m_j)
             stats = add_stats(stats, moe_st)
         return (x, stats), (jnp.stack(ks), jnp.stack(vs), jnp.stack(ms))
 
     (x, stats), (k_n, v_n, m_n) = _scan_layers(
-        cfg, params, body, (x, st.moe), (st.k, st.v, st.mass))
+        cfg, p_scan, body, (x, st.moe), (st.k, st.v, st.mass, blks))
     logits = _logits(params, cfg, x[:, -1:])[:, 0]
     return logits, PrefillState(k_n, v_n, m_n, stats)
 
@@ -526,7 +575,7 @@ def decode_step(params, cfg, cache: ModelCache, token: Array,
 
     A dense store is updated in place (`decode_in_place`): with the
     cache donated, the step's only writes to it are one row per slot and
-    layer."""
+    layer. Routed experts are read in place too (`_experts_out`)."""
     x = L.embed(params["embed"], token)
     sb, n_sb, kinds = sb_layout(cfg)
     aps, sps = attn_positions(cfg), ssm_positions(cfg)
@@ -547,12 +596,14 @@ def decode_step(params, cfg, cache: ModelCache, token: Array,
         kv = tuple(t.reshape(t.shape[0] * t.shape[1], *t.shape[2:])
                    for t in (cache.attn.k, cache.attn.v))
         attn_xs = cache.attn._replace(k=None, v=None)
+    p_scan, experts, _ = _experts_out(cfg, params)
 
     def body(carry, xs):
         x, kv, stats = carry
         p_sb, a_sl, s_sl, ks, ck, cv, i_sb = xs
         attn_pieces, ssm_pieces = [], []
         for i in range(sb):
+            p_i, moe_block = _sub(p_sb, experts, i, i_sb - cfg.lead_layers)
             mkv = None
             if has_cross:
                 mkv = (ck, cv, cache.cross_bias)
@@ -564,9 +615,9 @@ def decode_step(params, cfg, cache: ModelCache, token: Array,
                     layer = i_sb * len(aps) + j
                     piece = piece._replace(k=kv[0], v=kv[1])
                 x, piece, st = B.block_decode(
-                    p_sb[f"sub{i}"], x, cfg, "attn", spec, piece,
+                    p_i, x, cfg, "attn", spec, piece,
                     key=ks[j], memory_kv=mkv, append_mask=append_mask,
-                    layer=layer, live=live)
+                    layer=layer, live=live, moe_block=moe_block)
                 if in_place:
                     kv = (piece.k, piece.v)
                     piece = piece._replace(k=None, v=None)
@@ -575,8 +626,8 @@ def decode_step(params, cfg, cache: ModelCache, token: Array,
                 j = sps.index(i)
                 piece = jax.tree.map(lambda t: t[j], s_sl)
                 x, piece, st = B.block_decode(
-                    p_sb[f"sub{i}"], x, cfg, "ssm", spec, piece,
-                    memory_kv=mkv, live=live)
+                    p_i, x, cfg, "ssm", spec, piece,
+                    memory_kv=mkv, live=live, moe_block=moe_block)
                 ssm_pieces.append(piece)
             stats = add_stats(stats, st)
         a = (jax.tree.map(lambda *xs: jnp.stack(xs), *attn_pieces)
@@ -588,7 +639,7 @@ def decode_step(params, cfg, cache: ModelCache, token: Array,
     cross_k = cache.cross_k if has_cross else jnp.zeros((n_sb, 0))
     cross_v = cache.cross_v if has_cross else jnp.zeros((n_sb, 0))
     (x, kv, stats), (attn_c, ssm_c) = _scan_layers(
-        cfg, params, body, (x, kv, cache.moe),
+        cfg, p_scan, body, (x, kv, cache.moe),
         (attn_xs, cache.ssm, keys, cross_k, cross_v, jnp.arange(n_sb)))
     if in_place:
         attn_c = attn_c._replace(k=kv[0].reshape(kv_shapes[0]),

@@ -40,12 +40,14 @@ class MoEStats(NamedTuple):
     experts_hit: Array   # int32: experts given at least one of them
     calls: Array         # int32: MoE layer calls
     load_ratio: Array    # f32: busiest expert's assignments over the mean
+    inplace_calls: Array  # int32: calls whose grouped matmul kernel read
+                          # the layer-stacked expert weights in place
 
     @classmethod
     def zero(cls) -> "MoEStats":
         # one buffer a field: a donated cache may not hold one twice
         return cls(*(jnp.zeros((), jnp.int32) for _ in range(3)),
-                   jnp.zeros((), jnp.float32))
+                   jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32))
 
 
 def add_stats(total, st):
@@ -177,6 +179,7 @@ def moe_apply(
 def moe_apply_dropfree(
     p: dict, x: Array, *, top_k: int, norm_topk: bool = True,
     use_kernel: bool = False, live: Array | None = None,
+    block: Array | None = None,
 ) -> tuple[Array, MoEStats]:
     """Drop-free dispatch for serving. x: [B, T, d_model].
 
@@ -189,6 +192,12 @@ def moe_apply_dropfree(
     live: optional [B, T] bool, the rows that serve a request; the
     counters count only their assignments (all rows where None). What
     is computed does not depend on it.
+
+    block: optional int32 scalar — `p`'s gate, up and down are the
+    layer-stacked experts `[n_blocks * E, k, n]` (the serving loops hold
+    them out of the layer scan, `nn.model._experts_out`), and this layer's
+    are block `block`'s E; the kernel reads them where they lie
+    (`kernels/moe_gmm`).
 
     Returns (y [B, T, d_model], this call's `MoEStats`)."""
     from repro.kernels.moe_gmm import ops as gmm_ops
@@ -203,7 +212,8 @@ def moe_apply_dropfree(
     xs = x.reshape(N, Dm)[order // top_k]
 
     def mm(a, w):
-        return gmm_ops.grouped_matmul(a, w, sizes, use_kernel=use_kernel)
+        return gmm_ops.grouped_matmul(a, w, sizes, use_kernel=use_kernel,
+                                      block=block)
 
     g = mm(xs, p["gate"])
     h = (jax.nn.silu(g).astype(x.dtype)
@@ -213,19 +223,23 @@ def moe_apply_dropfree(
     y = jnp.einsum("nk,nkd->nd", top_vals.reshape(N, top_k),
                    y_tok.reshape(N, top_k, Dm))
     y = y.reshape(B, T, Dm).astype(x.dtype)
-    return _shared(p, x, y), _live_stats(flat_e, live, E, top_k)
+    st = _live_stats(flat_e, live, E, top_k,
+                     in_place=use_kernel and block is not None)
+    return _shared(p, x, y), st
 
 
-def _live_stats(flat_e, live, E, top_k) -> MoEStats:
+def _live_stats(flat_e, live, E, top_k, in_place: bool) -> MoEStats:
     """One call's counters over the live rows' assignments (flat_e:
-    [N * top_k] token-major expert ids; live: [B, T] bool or None)."""
+    [N * top_k] token-major expert ids; live: [B, T] bool or None;
+    in_place: the kernel read the stacked weights)."""
     w = (jnp.ones(flat_e.shape, jnp.int32) if live is None else
          jnp.repeat(live.reshape(-1).astype(jnp.int32), top_k))
     need = jnp.zeros((E,), jnp.int32).at[flat_e].add(w)
     n = jnp.sum(w)
     return MoEStats(n, jnp.sum(need > 0).astype(jnp.int32), jnp.int32(1),
                     jnp.max(need).astype(jnp.float32) * E
-                    / jnp.maximum(n, 1).astype(jnp.float32))
+                    / jnp.maximum(n, 1).astype(jnp.float32),
+                    jnp.int32(in_place))
 
 
 def moe_apply_dense(p: dict, x: Array, *, top_k: int,

@@ -115,8 +115,10 @@ class ContinuousGenerationResult:
     # decode rows of slots serving a request (MoE models only; empty
     # slots' rows are left out): assignments, experts_hit (experts with
     # at least one assignment, summed over layer calls), calls (MoE layer
-    # calls) and max_expert_load (the busiest expert's assignments over
-    # the mean, averaged over calls)
+    # calls), max_expert_load (the busiest expert's assignments over
+    # the mean, averaged over calls) and inplace_calls (calls whose
+    # grouped matmul kernel read the layer-stacked expert weights in
+    # place, `model._experts_out`; 0 without kernels)
     moe: Optional[dict] = None
     latent_store_bytes: int = 0   # MLA: bytes of the dense latent store
     pool_blocks: int = 0          # paged runs only: reserved pool size,
@@ -939,7 +941,8 @@ class Engine:
                        experts_hit=int(st.experts_hit),
                        calls=int(st.calls),
                        max_expert_load=float(st.load_ratio)
-                       / max(int(st.calls), 1))
+                       / max(int(st.calls), 1),
+                       inplace_calls=int(st.inplace_calls))
         latent = (int(cache.attn.k.nbytes)
                   if cache.attn is not None and kvcache.is_latent(cache.attn)
                   else 0)
@@ -1013,6 +1016,7 @@ class Engine:
             mx.counter("moe.assignments").inc(res.moe["assignments"])
             mx.counter("moe.experts_hit").inc(res.moe["experts_hit"])
             mx.counter("moe.calls").inc(res.moe["calls"])
+            mx.counter("moe.inplace_calls").inc(res.moe["inplace_calls"])
             mx.gauge("moe.max_expert_load").set(res.moe["max_expert_load"])
         if res.latent_store_bytes:
             mx.gauge("mla.latent_store_bytes").set(res.latent_store_bytes)

@@ -224,11 +224,11 @@ def test_dense_decode_step_updates_store_in_place(one_chip, no_compile_cache,
 _LATENT = re.compile(r"bf16\[(\d+,)*32,14208,640\]")
 
 
-def _latent_cfg():
+def _latent_cfg(num_layers=2):
     from repro.configs.base import MoEConfig, ModelConfig, YarnScaling
     return ModelConfig(
-        name="deepseek-v2-lite-2l", arch_type="moe", source="test",
-        num_layers=2, d_model=2048, num_heads=16, num_kv_heads=16,
+        name=f"deepseek-v2-lite-{num_layers}l", arch_type="moe",
+        source="test", num_layers=num_layers, d_model=2048, num_heads=16, num_kv_heads=16,
         d_ff=10944, vocab_size=102400, norm_eps=1e-6, kv_lora_rank=512,
         qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
         rope_yarn=YarnScaling(factor=40, original_max_position=4096,
@@ -311,3 +311,59 @@ def test_latent_prompt_chunk_compiles_for_v5e(one_chip, no_compile_cache,
         params, st, toks, c0).compile()
     assert re.search(r"%moe_gmm(\.\d+)? = ", compiled.as_text())
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
+
+
+# one layer's routed experts (gate and up, or down) at DeepSeek-V2-Lite's
+# widths: what a slice of the layer stack would be copied out as
+_EXPERTS = re.compile(r"bf16\[64,(2048,1408|1408,2048)\]")
+
+
+def test_latent_step_reads_expert_stack_in_place(one_chip, no_compile_cache,
+                                                 monkeypatch):
+    """The decode step and a 512-token prompt chunk at DeepSeek-V2-Lite's
+    widths with two expert layers (the dense layer, then a stack of 2):
+    the grouped expert matmul is there, and no op copies or slices a
+    layer's experts out of the stack before it — the kernel reads them
+    where they lie."""
+    from repro.kernels.decode_mla import ops as mla_ops
+    from repro.kernels.moe_gmm import ops as gmm_ops
+    monkeypatch.setattr(mla_ops, "resolve_interpret", lambda flag: False)
+    monkeypatch.setattr(gmm_ops, "resolve_interpret", lambda flag: False)
+    cfg = _latent_cfg(num_layers=3)
+    spec = dataclasses.replace(FULL, budget=14208)
+    on_chip = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        tree)
+    params = on_chip(jax.eval_shape(
+        lambda: M.init_params(jax.random.key(0), cfg)))
+    assert params["blocks"]["sub0"]["moe"]["gate"].shape == (2, 64, 2048,
+                                                            1408)
+    cache = on_chip(jax.eval_shape(lambda: M.init_cache(cfg, spec, 32,
+                                                        14208)))
+    tok = jax.ShapeDtypeStruct((32, 1), jnp.int32, sharding=one_chip)
+    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+    live = jax.ShapeDtypeStruct((32,), jnp.bool_, sharding=one_chip)
+    st = on_chip(jax.eval_shape(lambda: M.init_prefill_state(cfg, 13696)))
+    toks = jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=one_chip)
+    c0 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+
+    def step(p, c, t, k, live):
+        logits, c = M.decode_step(p, cfg, c, t, spec, key=k, live=live)
+        return jnp.argmax(logits, -1), c
+
+    def chunk(p, s, t, c):
+        return M.prefill_chunk(p, cfg, s, t, c, spec)
+
+    for text in (jax.jit(step, donate_argnums=(1,)).lower(
+                     params, cache, tok, key, live).compile().as_text(),
+                 jax.jit(chunk, donate_argnums=(1,)).lower(
+                     params, st, toks, c0).compile().as_text()):
+        assert re.search(r"%moe_gmm(\.\d+)? = ", text)
+        bad = []
+        for line in text.splitlines():
+            m = _INSTR.match(line)
+            if m and _EXPERTS.search(m.group(2)) and m.group(3) in (
+                    "copy", "copy-start", "copy-done", "dynamic-slice",
+                    "fusion"):
+                bad.append(line[:160])
+        assert bad == []

@@ -139,9 +139,14 @@ def _serve(params, cfg, prompt, fed, *, chunk=None, other=None, slots=2):
     return np.stack(out), cache
 
 
-def test_prefill_then_decode_matches_reference(ref, params, cfg):
+@pytest.mark.parametrize("use_kernels", [False, True])
+def test_prefill_then_decode_matches_reference(ref, params, cfg, use_kernels):
+    """Monolithic prefill, then decode steps; with kernels on (interpret
+    mode) the grouped matmul reads both MoE layers' experts from the
+    layer stack where they lie (blocks 0 and 1)."""
+    c = dataclasses.replace(cfg, use_kernels=use_kernels)
     prompt, fed = _prompt(0, 40), _prompt(1, 6)
-    got, cache = _serve(params, cfg, prompt, fed)
+    got, cache = _serve(params, c, prompt, fed)
     want = _reference(ref, params, np.concatenate([prompt, fed]),
                       np.arange(39, 46))
     np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
@@ -149,6 +154,10 @@ def test_prefill_then_decode_matches_reference(ref, params, cfg):
     assert cache.attn.k.shape[-1] == cfg.latent_store_dim == 128
     assert cache.attn.v.shape[-1] == 0
     assert int(cache.moe.assignments) == 2 * 2 * (40 + 2 * 6)
+    # 2 MoE layers a prefill or decode step: 1 prefill and 6 steps
+    assert int(cache.moe.calls) == 2 * (1 + 6)
+    assert int(cache.moe.inplace_calls) == (int(cache.moe.calls)
+                                            if use_kernels else 0)
 
 
 @pytest.mark.parametrize("use_kernels", [False, True])
@@ -243,6 +252,27 @@ def test_engine_counts_only_live_slots(params, cfg):
     # fetch trails the dispatch by one step): at most one row a request
     assert per_row * rows <= res.moe["assignments"] <= per_row * (rows + 3)
     assert res.moe["assignments"] < per_row * (128 + 2 * res.decode_steps)
+
+
+@pytest.mark.parametrize("use_kernels", [False, True])
+def test_engine_counts_inplace_expert_reads(params, cfg, use_kernels):
+    """`moe.inplace_calls` counts every MoE layer call of an engine run
+    (prompt chunks and decode steps) when the grouped matmul kernel
+    reads the layer-stacked experts in place, and none without it."""
+    from repro.obs import Metrics
+    mx = Metrics()
+    eng = Engine(dataclasses.replace(cfg, use_kernels=use_kernels), params,
+                 presets(budget=0, window=16)["full"], buckets=[32],
+                 max_new=3, slots=2, chunked_prefill=True, chunk_len=16,
+                 metrics=mx)
+    res = eng.generate_continuous([Request(tokens=_prompt(30 + i, 32),
+                                           max_new=3) for i in range(2)])
+    assert res.moe["calls"] > 0
+    assert res.moe["inplace_calls"] == (res.moe["calls"] if use_kernels
+                                        else 0)
+    snap = mx.snapshot()
+    assert snap["moe.inplace_calls"] == res.moe["inplace_calls"]
+    assert snap["moe.calls"] == res.moe["calls"]
 
 
 def test_yarn_frequencies_and_scale(cfg):

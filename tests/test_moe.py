@@ -78,6 +78,32 @@ def test_dropfree_matches_dense_and_counts(use_kernel):
     assert float(st.load_ratio) == pytest.approx(load.max() / (30 / 4))
 
 
+@pytest.mark.parametrize("use_kernel", [False, True])
+@pytest.mark.parametrize("block", [0, 1, 2])
+def test_grouped_matmul_reads_its_block_of_the_stack(use_kernel, block):
+    """The grouped matmul over a stack of 3 blocks' experts, `[3 * E, k,
+    n]`, at block `block` equals the call on that block's own `[E, k, n]`
+    slice, with one expert given no rows and the rows not a whole row
+    tile; bit for bit, with the kernel (interpret mode, whose only change
+    is which weights a tile reads) and with the oracle."""
+    from repro.kernels.moe_gmm import ops as gmm_ops
+    E, k, n = 4, 32, 48
+    stack = jax.random.normal(jax.random.key(13), (3 * E, k, n))
+    sizes = jnp.array([5, 0, 6, 2], jnp.int32)
+    lhs = jax.random.normal(jax.random.key(14), (13, k))
+    got = gmm_ops.grouped_matmul(lhs, stack, sizes, use_kernel=use_kernel,
+                                 block=jnp.int32(block), interpret=True)
+    want = gmm_ops.grouped_matmul(lhs, stack[block * E:(block + 1) * E],
+                                  sizes, use_kernel=use_kernel,
+                                  interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # and the oracle on the slice agrees with it
+    ref = gmm_ops.grouped_matmul_ref(lhs, stack[block * E:(block + 1) * E],
+                                     sizes)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               atol=1e-4, rtol=1e-5)
+
+
 def test_dropfree_counts_live_rows_only():
     """Rows marked not live (a decode step's empty slots) are computed
     as before but left out of the counters."""
@@ -123,4 +149,4 @@ def test_zero_stats_hold_a_buffer_each():
     buffer donated twice, so no two fields may share one."""
     st = MoE.MoEStats.zero()
     ptrs = {x.unsafe_buffer_pointer() for x in st}
-    assert len(ptrs) == len(st) == 4
+    assert len(ptrs) == len(st) == 5
